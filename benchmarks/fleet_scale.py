@@ -221,6 +221,8 @@ def fleet_suite(K: int = 300):
 def main(argv=None) -> int:
     """Write BENCH_fleet.json: convergence + pricing vs population size,
     cohort size, churn and selection rule, diffable PR-to-PR."""
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--K", type=int, default=300)
     p.add_argument("--out", default="BENCH_fleet.json")

@@ -177,6 +177,8 @@ def graph_suite(K: int = 400):
 def main(argv=None) -> int:
     """Write BENCH_graph.json: lazy-vs-dense gossip bytes at matched loss
     across graph families, diffable PR-to-PR."""
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--K", type=int, default=400)
     p.add_argument("--out", default="BENCH_graph.json")

@@ -308,6 +308,8 @@ def xi_tradeoff(K: int = 3000):
 def main(argv=None) -> int:
     """Write BENCH_lag_convex.json: per-policy rounds AND wire bytes to the
     target accuracy, so the convex-bench trajectory can be diffed PR-to-PR."""
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--K", type=int, default=3000)
     p.add_argument("--bits", type=int, default=4)

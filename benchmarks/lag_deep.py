@@ -62,6 +62,8 @@ ALL_BENCHES = [lag_trainer_bench]
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--workers", type=int, default=8)

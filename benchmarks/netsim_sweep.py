@@ -161,6 +161,8 @@ def netsim_suite(K: int = 4000, steps: int = 50):
 def main(argv=None) -> int:
     """Write BENCH_netsim.json: the rounds/wall-clock-vs-heterogeneity
     trend plus async staleness sensitivity, diffable PR-to-PR."""
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--K", type=int, default=4000)
     p.add_argument("--steps", type=int, default=50)

@@ -295,6 +295,8 @@ def devrun_record(quick: bool = False):
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--quick", action="store_true")
     p.add_argument("--out", default="BENCH_perf_comm.json")

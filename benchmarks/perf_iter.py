@@ -23,10 +23,12 @@ import json
 import jax
 
 from repro.launch import dryrun as dr
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--shape", required=True)
@@ -64,7 +66,7 @@ def main():
 
     import time
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fn, arg_shapes, in_sh, out_sh = dr.build_lowerable(
             cfg, args.shape, mesh, workers,
             seq_shard=not args.no_input_seq_shard, mode=args.mode)

@@ -17,6 +17,8 @@ jax.config.update("jax_enable_x64", True)   # the convex repro needs 1e-8 gaps
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--quick", action="store_true")
     p.add_argument("--dryrun-dir", default="experiments/dryrun")

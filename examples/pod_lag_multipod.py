@@ -19,7 +19,7 @@ from repro.configs import get_config
 from repro.data import TokenStream, make_heterogeneous_inputs
 from repro.dist import pod_lag
 from repro.dist.lag_trainer import TrainerConfig
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -39,7 +39,7 @@ def main():
 
     grad_bytes = sum(x.size * 4 for x in jax.tree_util.tree_leaves(
         state["params"]))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for step in range(args.steps):
             state, m = step_fn(state, batch)
             if step % 10 == 0 or step == args.steps - 1:

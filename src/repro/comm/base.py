@@ -145,10 +145,13 @@ class CommPolicy:
         """Candidate upload (payload, aux).  Dense family: the gradient
         innovation δ∇ = ∇L_m(θ^k) − ĝ_m, bit-exactly the masked-delta math
         of the pre-policy drivers (stale ĝ cast to the fresh grad dtype)."""
-        payload = jax.tree_util.tree_map(
-            lambda g, gh: g - gh.astype(g.dtype), ctx.grad_new,
-            st["grad_hat"])
-        return payload, {}
+        return self.innovation(ctx.grad_new, st["grad_hat"]), {}
+
+    @staticmethod
+    def innovation(grads: Pytree, grad_hat: Pytree) -> Pytree:
+        """δ∇ = ∇L_m(θ^k) − ĝ_m (stale ĝ cast to the fresh grad dtype)."""
+        return jax.tree_util.tree_map(lambda g, gh: g - gh.astype(g.dtype),
+                                      grads, grad_hat)
 
     def should_upload(self, ctx: CommRound, st: PolicyState, payload: Pytree,
                       aux: Dict[str, Any]) -> jnp.ndarray:

@@ -59,7 +59,12 @@ class LAGWKPolicy(CommPolicy):
 
     def fast_precompute(self, plan, grads, st, *, theta, theta_stacked,
                         grad_at_hat=None):
-        return {"lhs_sq": plan.delta_sqnorm(grads, st["grad_hat"])}
+        # ‖δ∇‖² of the innovation the encode builds anyway (the same
+        # subtraction, merged by XLA): the plane then flattens ONE
+        # param-sized buffer for both the trigger and the ĝ fold, where
+        # flattening g and ĝ apart costs a third copy in device memory
+        return {"lhs_sq": plan.sqnorm(self.innovation(grads,
+                                                      st["grad_hat"]))}
 
 
 class LAGPSPolicy(CommPolicy):

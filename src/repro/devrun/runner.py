@@ -40,10 +40,10 @@ Design constraints, in order:
      execution — the double-buffered schedule, with at most two round
      states live at once (the in-flight donated one and the result).
 
-On a process with fewer devices than workers (``DeviceWorkers.
-available()`` False — e.g. the default single-CPU test process) the
-builders fall back to the vmapped ``repro.dist.lag_trainer`` step, which
-is the same trajectory; CI exercises the real multi-device path with
+On a process with fewer devices than workers the builders raise
+(``DeviceWorkers.require``): a run that asked for D devices never
+quietly becomes a one-device run.  ``devices:1`` runs the real plane on
+any single device; CI exercises D > 1 with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` subprocess tests.
 """
 from __future__ import annotations
@@ -60,11 +60,6 @@ from repro.engine import topology as topo_lib
 from repro.fastpath.layout import LANES, FlatLayout
 from repro.models import model
 from repro.models.common import ModelConfig
-
-try:  # jax >= 0.4.35 spelling
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax moved it
-    from jax.sharding import shard_map  # type: ignore
 
 Pytree = Any
 
@@ -101,28 +96,29 @@ def init_device_state(key, cfg: ModelConfig, tcfg, policy=None, server=None,
     shared state (params, aggregate ∇, history, opt state) is
     replicated.  Placement at init (rather than reshard-on-entry every
     step) is what lets ``donate_argnums`` actually reuse the buffers:
-    donated input and output shardings match from round 0.  Falls back
-    to plain host state when the process lacks the devices.
+    donated input and output shardings match from round 0.  The
+    per-worker state is built in place, shard by shard: the D-stacked
+    mirrors of a full-width model do not fit on one device.  The
+    parameters come from the same eager ``model.init`` as every other
+    topology.  Raises when the process lacks the devices.
     """
     policy, server, topology = _resolve(cfg, tcfg, policy, server, topology)
-    state = lag_trainer.init_state(key, cfg, tcfg, policy=policy,
-                                   server=server, topology=topology)
-    if not topology.available(tcfg.num_workers):
-        return state
     mesh = topology.device_mesh(tcfg.num_workers)
-
-    def put(tree, spec):
-        return jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, NamedSharding(mesh, spec)), tree)
-
     per_worker = set(policy.state_keys) | {"comm_per_worker", "L_m"}
-    lag_state = {k: put(v, P("workers")) if k in per_worker else put(v, P())
-                 for k, v in state["lag"].items()}
-    out = dict(state, lag=lag_state, params=put(state["params"], P()),
-               step=put(state["step"], P()))
-    if "opt" in state:
-        out["opt"] = put(state["opt"], P())
-    return out
+
+    def build(params):
+        return lag_trainer.state_for_params(params, tcfg, policy=policy,
+                                            server=server, topology=topology)
+
+    def placement(path, _):
+        keys = [getattr(k, "key", None) for k in path[:2]]
+        worker_dim = keys[0] == "lag" and keys[1] in per_worker
+        return NamedSharding(mesh, P("workers") if worker_dim else P())
+
+    params = model.init(key, cfg)
+    shardings = jax.tree_util.tree_map_with_path(
+        placement, jax.eval_shape(build, params))
+    return jax.jit(build, out_shardings=shardings)(params)
 
 
 # ---------------------------------------------------------------------------
@@ -142,11 +138,6 @@ def make_device_step(cfg: ModelConfig, tcfg, policy=None, server=None,
     topologies exactly.
     """
     policy, server, topology = _resolve(cfg, tcfg, policy, server, topology)
-    if not topology.available(tcfg.num_workers):
-        # same math, one device: the vmapped sync trainer
-        return lag_trainer.make_train_step(cfg, tcfg, policy=policy,
-                                           server=server,
-                                           schedule_seed=schedule_seed)
     mesh = topology.device_mesh(tcfg.num_workers)
 
     def train_step(state: Dict, batch: Dict) -> Tuple[Dict, Dict]:
@@ -199,12 +190,12 @@ def make_device_step(cfg: ModelConfig, tcfg, policy=None, server=None,
             sum_delta = layout.unflatten(sum_flat, like=jnp.float32)
             return gmask, losses, new_pst, sum_delta
 
-        gmask, losses, new_pst, sum_delta = shard_map(
+        gmask, losses, new_pst, sum_delta = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P("workers"), P("workers"), P("workers"),
                       P(), P(), P(), P()),
             out_specs=(P(), P("workers"), P("workers"), P()),
-            check_rep=False,
+            check_vma=False,
         )(pst, L_arr, shards, params, lag_state["hist"], state["step"], key)
 
         loss = server.composite_loss(jnp.mean(losses), params)

@@ -191,8 +191,14 @@ def init_state(key, cfg: ModelConfig, tcfg: TrainerConfig,
     """Fresh trainer state.  ``grad_hat`` starts at zero with an empty
     history, so round 0 triggers every worker (lhs ‖∇L_m‖² > rhs 0) and
     delivers the exact first GD step — the paper's all-upload init."""
+    return state_for_params(model.init(key, cfg), tcfg, policy=policy,
+                            server=server, topology=topology)
+
+
+def state_for_params(params, tcfg: TrainerConfig, policy=None, server=None,
+                     topology=None) -> Dict:
+    """:func:`init_state` around given parameters (jit-traceable)."""
     W = tcfg.num_workers
-    params = model.init(key, cfg)
     policy = policy if policy is not None else tcfg.comm_policy()
     server = server if server is not None else tcfg.server_optimizer()
     gh_dtype = jnp.dtype(tcfg.grad_hat_dtype) if tcfg.grad_hat_dtype \

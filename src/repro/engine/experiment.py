@@ -305,9 +305,9 @@ class Experiment:
                 schedule_seed=self.seed))
         elif getattr(topo, "name", None) == "devices":
             # real multi-device plane: shard_map workers + packed wire
-            # collectives, falling back to the vmapped step on a process
-            # without the devices (function-level import — repro.devrun
-            # consumes the engine, like repro.dist)
+            # collectives; raises on a process without the devices
+            # (function-level import — repro.devrun consumes the engine,
+            # like repro.dist)
             from repro import devrun
             state = devrun.init_device_state(
                 jax.random.PRNGKey(self.seed), cfg, tcfg, policy=policy,

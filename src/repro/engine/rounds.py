@@ -131,11 +131,14 @@ def policy_rounds(policy: CommPolicy, lagcfg: lag.LAGConfig, params: Pytree,
                 f"{sorted({str(l.dtype) for l in jax.tree_util.tree_leaves(grads)})}"
                 f" — use fastpath='auto'/'off' for x64 runs")
         plan = None
-    if plan is not None and plan.below_dispatch_floor(grads):
+    if plan is not None and (plan.below_dispatch_floor(grads)
+                             or plan.partitioned_by_context()):
         # auto mode only: tiny stacked trees (rows × workers below the
         # static floor) run the jnp oracle outright — the batched launch
         # cannot amortize its flatten/scatter overhead there (the
-        # convex-d50 M=1 regression BENCH_perf_comm.json pinned)
+        # convex-d50 M=1 regression BENCH_perf_comm.json pinned) — and so
+        # does a round a multi-device mesh partitions, which Mosaic
+        # kernels cannot follow
         plan = None
     fast = None
     if plan is not None:

@@ -247,12 +247,11 @@ class DeviceWorkers(Topology):
     b-bit integer codes + per-leaf quantizer steps, not dense f32),
     gathered and summed in worker order so the reduction is bit-exact
     with the in-process ``sum_reduce``.  The step builder lives in
-    ``repro.devrun.runner``; on a machine with fewer devices than
-    workers (``available()`` False — e.g. the default 1-CPU test
-    process) drivers fall back to the vmapped ``BatchShards`` math,
-    which is the same trajectory.  CI exercises the real multi-device
-    path via ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-    subprocess tests.
+    ``repro.devrun.runner``; on a process with fewer devices than
+    workers, building the mesh raises (``require``) rather than running
+    a different topology.  CI exercises the multi-device path via
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` subprocess
+    tests.
     """
     name = "devices"
 
@@ -268,10 +267,21 @@ class DeviceWorkers(Topology):
         """True when this process actually has enough devices."""
         return len(jax.devices()) >= self.num_devices(default)
 
+    def require(self, default: int = None) -> None:
+        """Raise unless this process has the D devices the spec names."""
+        if not self.available(default):
+            found = jax.devices()
+            raise RuntimeError(
+                f"devices:{self.num_devices(default)} needs "
+                f"{self.num_devices(default)} devices; this process sees "
+                f"{len(found)}: {found}")
+
     def device_mesh(self, default: int = None):
         """1-D ``("workers",)`` mesh over the first D devices."""
         from repro.launch.mesh import make_mesh
-        return make_mesh((self.num_devices(default),), ("workers",))
+        self.require(default)
+        D = self.num_devices(default)
+        return make_mesh((D,), ("workers",), devices=jax.devices()[:D])
 
 
 # ---------------------------------------------------------------------------

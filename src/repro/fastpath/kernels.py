@@ -7,22 +7,26 @@ Workers are VECTORIZED inside each block: a grid step reads a
 ``(W_chunk, BLOCK_ROWS, LANES)`` slab, so the worker dim rides the VPU's
 batch lanes instead of serializing the grid (what a vmapped per-leaf
 launch gets for free, preserved here), with ``MAX_WORKER_BLOCK`` capping
-the slab so VMEM stays bounded on real hardware (16 workers × 128 KiB =
-2 MiB per f32 operand); larger fleets tile over worker-chunks.  The
+the slab so VMEM stays bounded on real hardware (8 workers × 128 KiB =
+1 MiB per f32 operand); larger fleets tile over worker-chunks.  The
 worker dim is zero-padded up to the chunk multiple — zeros are absorbing
 for every plane op and the wrappers slice the pad back off.
 
 Reductions never accumulate across grid steps: each (chunk, block) cell
 writes per-(worker, SUB-BLOCK) partials — the layout's leaf-padding
-granularity, so partials never mix leaves — to a ``(W, nsubs)`` output,
-and the deterministic fixed-order segment reduction down to
-per-(worker, leaf) scalars happens in plain jnp in
-:mod:`repro.fastpath.plan`.  Per-sub-block quantizer scales enter the
-LAQ kernel the same way (a ``(W_chunk, SUBS_PER_BLOCK)`` block), so
-batching preserves LAQ's per-leaf grid.  Second operands may be
-UNSTACKED ``(rows, LANES)`` (e.g. the shared θ^k under a per-worker θ̂_m
-sweep): their BlockSpec ignores the worker-chunk index, so the broadcast
-costs no extra HBM.
+granularity, so partials never mix leaves — and the deterministic
+fixed-order segment reduction down to per-(worker, leaf) scalars happens
+in plain jnp in :mod:`repro.fastpath.plan`.  Per-sub-block quantizer
+steps enter the LAQ kernel the same way, so batching preserves LAQ's
+per-leaf grid.  Across the ``pallas_call`` boundary these scalars are
+laid out ``(row_blocks, W, subs_per_step)`` with a ``(1, W_chunk,
+subs_per_step)`` block: Mosaic needs a block's last two dims to be
+(8, 128)-divisible or whole, and here they are the whole sub-block axis
+of one step and either all workers or an 8-worker chunk.  The wrappers
+convert to and from the ``(W, nsubs)`` view in jnp.  Second operands
+may be UNSTACKED ``(rows, LANES)`` (e.g. the shared θ^k under a
+per-worker θ̂_m sweep): their BlockSpec ignores the worker-chunk index,
+so the broadcast costs no extra HBM.
 
 All compute is float32 (the jnp oracle's convention); callers cast at
 scatter time.  On CPU the kernels run in interpret mode — parity
@@ -38,8 +42,11 @@ from jax.experimental import pallas as pl
 
 from repro.fastpath.layout import BLOCK_ROWS, LANES, SUB_ROWS
 
-#: cap on workers per block: 16 × (256, 128) f32 = 2 MiB VMEM per operand
-MAX_WORKER_BLOCK = 16
+#: cap on workers per block: 8 × (256, 128) f32 = 1 MiB VMEM per operand.
+#: The LAQ encode streams five slab operands, double-buffered: at 16
+#: workers that is 20 MiB, over v5e's 16 MiB scoped-VMEM limit (Mosaic
+#: refuses it); at 8 it is 10 MiB.
+MAX_WORKER_BLOCK = 8
 
 # masked-combine modes: how (candidate a, state b, per-worker mask m) fold
 MASK_MODES = ("add", "update", "select")
@@ -74,8 +81,27 @@ def _data_spec(ndim: int, wc: int, rows: int) -> pl.BlockSpec:
 
 
 def _sub_spec(wc: int, rows: int) -> pl.BlockSpec:
-    """(wc, subs-per-step) spec for per-(worker, sub-block) scalars."""
-    return pl.BlockSpec((wc, rows // SUB_ROWS), lambda w, i: (w, i))
+    """(1, wc, subs-per-step) spec for per-(worker, sub-block) scalars
+    stored ``(row_blocks, Wp, subs_per_step)``."""
+    return pl.BlockSpec((1, wc, rows // SUB_ROWS), lambda w, i: (i, w, 0))
+
+
+def _sub_shape(Wp: int, R: int, rows: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((R // rows, Wp, rows // SUB_ROWS),
+                                jnp.float32)
+
+
+def _to_blocked(x: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """(Wp, nsubs) → (row_blocks, Wp, subs_per_step)."""
+    Wp, nsubs = x.shape
+    per = rows // SUB_ROWS
+    return x.reshape(Wp, nsubs // per, per).transpose(1, 0, 2)
+
+
+def _from_blocked(x: jnp.ndarray, W: int) -> jnp.ndarray:
+    """(row_blocks, Wp, subs_per_step) → (W, nsubs)."""
+    nb, Wp, per = x.shape
+    return x.transpose(1, 0, 2).reshape(Wp, nb * per)[:W]
 
 
 def _worker_spec(wc: int) -> pl.BlockSpec:
@@ -92,23 +118,36 @@ def _slab(ref) -> jnp.ndarray:
     return x.reshape(x.shape[0], -1, SUB_ROWS, LANES)
 
 
+def _per_sub(reduce, x: jnp.ndarray) -> jnp.ndarray:
+    """(wc, subs, SUB_ROWS, LANES) → (1, wc, subs): sublanes first, then
+    lanes — Mosaic reduces one trailing dim at a time."""
+    return reduce(reduce(x, axis=2), axis=-1)[None]
+
+
+def _sub_bcast(s: jnp.ndarray) -> jnp.ndarray:
+    """(1, wc, subs) per-sub-block scalars → (wc, subs, 1, LANES), which
+    broadcasts over a slab's sub-block rows."""
+    s = s[0][:, :, None]
+    return jnp.broadcast_to(s, s.shape[:2] + (LANES,))[:, :, None, :]
+
+
 # ---------------------------------------------------------------------------
 # Per-sub-block partial reductions (one write per grid cell)
 # ---------------------------------------------------------------------------
 
 def _delta_sq_kernel(a_ref, b_ref, out_ref):
     d = _slab(a_ref) - _slab(b_ref)
-    out_ref[...] = jnp.sum(d * d, axis=(2, 3)).reshape(out_ref.shape)
+    out_ref[...] = _per_sub(jnp.sum, d * d)
 
 
 def _sq_kernel(a_ref, out_ref):
     x = _slab(a_ref)
-    out_ref[...] = jnp.sum(x * x, axis=(2, 3)).reshape(out_ref.shape)
+    out_ref[...] = _per_sub(jnp.sum, x * x)
 
 
 def _absmax_kernel(g_ref, q_ref, e_ref, out_ref):
     v = _slab(g_ref) - _slab(q_ref) + _slab(e_ref)
-    out_ref[...] = jnp.max(jnp.abs(v), axis=(2, 3)).reshape(out_ref.shape)
+    out_ref[...] = _per_sub(jnp.max, jnp.abs(v))
 
 
 def _partials(kernel, ops, *, interpret: bool) -> jnp.ndarray:
@@ -121,10 +160,10 @@ def _partials(kernel, ops, *, interpret: bool) -> jnp.ndarray:
         grid=(Wp // wc, R // rows),
         in_specs=[_data_spec(op.ndim, wc, rows) for op in ops],
         out_specs=_sub_spec(wc, rows),
-        out_shape=jax.ShapeDtypeStruct((Wp, R // SUB_ROWS), jnp.float32),
+        out_shape=_sub_shape(Wp, R, rows),
         interpret=interpret,
     )(*ops)
-    return out[:W]
+    return _from_blocked(out, W)
 
 
 def delta_sqnorm_blocks(a: jnp.ndarray, b: jnp.ndarray,
@@ -156,13 +195,13 @@ def _laq_kernel(qmax, g_ref, q_ref, e_ref, s_ref, p_ref, eout_ref, sq_ref):
     # f32 grid the payload multiply uses is also the value the
     # collective wire format transmits; a division in the kernel body
     # could round differently from one in the surrounding module
-    step = s_ref[...].astype(jnp.float32)[:, :, None, None]
+    step = _sub_bcast(s_ref[...].astype(jnp.float32))
     inv = jnp.where(step > 0.0, 1.0 / jnp.where(step > 0.0, step, 1.0), 0.0)
     codes = jnp.clip(jnp.round(v * inv), -qmax, qmax)
     p = codes * step
     p_ref[...] = p.reshape(p_ref.shape)
     eout_ref[...] = (v - p).reshape(eout_ref.shape)
-    sq_ref[...] = jnp.sum(p * p, axis=(2, 3)).reshape(sq_ref.shape)
+    sq_ref[...] = _per_sub(jnp.sum, p * p)
 
 
 def laq_encode_blocks(g: jnp.ndarray, q: jnp.ndarray, e: jnp.ndarray,
@@ -180,7 +219,7 @@ def laq_encode_blocks(g: jnp.ndarray, q: jnp.ndarray, e: jnp.ndarray,
     wc, Wp, rows = _tiling(W, R, interpret)
     qmax = float(2 ** (bits - 1) - 1)
     gp, qp, ep = (_pad_w(x, Wp) for x in (g, q, e))
-    sp = _pad_w(steps_subs, Wp)
+    sp = _to_blocked(_pad_w(steps_subs, Wp), rows)
     p, eout, sq = pl.pallas_call(
         functools.partial(_laq_kernel, qmax),
         grid=(Wp // wc, R // rows),
@@ -189,10 +228,10 @@ def laq_encode_blocks(g: jnp.ndarray, q: jnp.ndarray, e: jnp.ndarray,
                    _sub_spec(wc, rows)],
         out_shape=[jax.ShapeDtypeStruct((Wp,) + g.shape[1:], jnp.float32),
                    jax.ShapeDtypeStruct((Wp,) + g.shape[1:], jnp.float32),
-                   jax.ShapeDtypeStruct((Wp, R // SUB_ROWS), jnp.float32)],
+                   _sub_shape(Wp, R, rows)],
         interpret=interpret,
     )(gp, qp, ep, sp)
-    return p[:W], eout[:W], sq[:W]
+    return p[:W], eout[:W], _from_blocked(sq, W)
 
 
 # ---------------------------------------------------------------------------
@@ -232,6 +271,9 @@ def masked_combine(a: jnp.ndarray, b: jnp.ndarray, mask: jnp.ndarray,
                   _worker_spec(wc)],
         out_specs=_data_spec(3, wc, rows),
         out_shape=jax.ShapeDtypeStruct(bp.shape, jnp.float32),
+        # the fold is in place: each grid cell reads its block of b
+        # before writing the same block, so b's flat copy is the output
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(a, bp, m2d)
     return out[:W]

@@ -106,8 +106,11 @@ class FlatLayout:
     # -- flatten ------------------------------------------------------------
 
     def _flat_leaves(self, tree: Pytree, lead: Tuple[int, ...]):
-        """Per-leaf (lead + (padded_size,)) float32 segments, pytree order,
-        plus the zero tail up to a whole grid block."""
+        """Per-leaf ``lead + (leaf_rows, LANES)`` float32 segments, pytree
+        order, plus the zero tail up to a whole grid block.  Segments are
+        row-shaped so the concatenation is the buffer itself: a flat
+        ``(…, size)`` concat reshaped to rows would be a second full-size
+        copy on TPU, where the two shapes tile differently."""
         leaves = jax.tree_util.tree_leaves(tree)
         if len(leaves) != self.num_leaves:
             raise ValueError(f"tree has {len(leaves)} leaves, layout expects "
@@ -120,18 +123,18 @@ class FlatLayout:
             pad = subs * SUB - size
             if pad:
                 flat = jnp.pad(flat, [(0, 0)] * len(lead) + [(0, pad)])
-            segs.append(flat)
+            segs.append(flat.reshape(lead + (subs * SUB_ROWS, LANES)))
         tail = self.nblocks * SUBS_PER_BLOCK - self.nsubs
         if tail:
-            segs.append(jnp.zeros(lead + (tail * SUB,), jnp.float32))
+            segs.append(jnp.zeros(lead + (tail * SUB_ROWS, LANES),
+                                  jnp.float32))
         return segs
 
     def flatten(self, tree: Pytree) -> jnp.ndarray:
         """Template-shaped tree → ``(rows, LANES)`` float32 buffer."""
         if self.nblocks == 0:
             return jnp.zeros((0, LANES), jnp.float32)
-        return jnp.concatenate(self._flat_leaves(tree, ()),
-                               axis=0).reshape(-1, LANES)
+        return jnp.concatenate(self._flat_leaves(tree, ()), axis=0)
 
     def flatten_stacked(self, tree: Pytree) -> jnp.ndarray:
         """Stacked ``(W, …leaf)`` tree → ``(W, rows, LANES)`` float32."""
@@ -139,10 +142,7 @@ class FlatLayout:
         W = leaves[0].shape[0]
         if self.nblocks == 0:
             return jnp.zeros((W, 0, LANES), jnp.float32)
-        # explicit rows (not -1): W may be 0 — an empty cohort stacks to
-        # an empty buffer instead of tripping reshape's inference
-        return jnp.concatenate(self._flat_leaves(tree, (W,)),
-                               axis=1).reshape(W, self.rows, LANES)
+        return jnp.concatenate(self._flat_leaves(tree, (W,)), axis=1)
 
     # -- scatter back -------------------------------------------------------
 
@@ -157,30 +157,33 @@ class FlatLayout:
         return tuple(jnp.dtype(l.dtype)
                      for l in jax.tree_util.tree_leaves(like))
 
-    def _leaf_from_flat(self, flat: jnp.ndarray, i: int,
+    def _leaf_from_rows(self, buf: jnp.ndarray, i: int,
                         lead: Tuple[int, ...], dtype) -> jnp.ndarray:
+        """Leaf ``i`` out of a ``lead + (rows, LANES)`` buffer: slice its
+        rows first, so only the leaf's own segment is reshaped."""
         shape = self.shapes[i]
         size, subs = self.sizes[i], self.leaf_subs[i]
         if subs == 0:
             return jnp.zeros(lead + shape, dtype)
-        off = self.leaf_sub_offsets[i] * SUB
-        seg = jax.lax.slice_in_dim(flat, off, off + size, axis=len(lead))
+        r0, nr = self.leaf_sub_offsets[i] * SUB_ROWS, subs * SUB_ROWS
+        ax = len(lead)
+        seg = jax.lax.slice_in_dim(buf, r0, r0 + nr, axis=ax)
+        seg = jax.lax.slice_in_dim(seg.reshape(lead + (nr * LANES,)),
+                                   0, size, axis=ax)
         return seg.reshape(lead + shape).astype(dtype)
 
     def unflatten(self, buf: jnp.ndarray, like: Any = None) -> Pytree:
         """``(rows, LANES)`` buffer → template tree (leaf dtypes restored)."""
-        flat = buf.reshape(-1)
         dts = self._out_dtypes(like)
-        leaves = [self._leaf_from_flat(flat, i, (), dts[i])
+        leaves = [self._leaf_from_rows(buf, i, (), dts[i])
                   for i in range(self.num_leaves)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def unflatten_stacked(self, buf: jnp.ndarray, like: Any = None) -> Pytree:
         """``(W, rows, LANES)`` buffer → stacked template tree."""
         W = buf.shape[0]
-        flat = buf.reshape(W, self.rows * LANES)
         dts = self._out_dtypes(like)
-        leaves = [self._leaf_from_flat(flat, i, (W,), dts[i])
+        leaves = [self._leaf_from_rows(buf, i, (W,), dts[i])
                   for i in range(self.num_leaves)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 

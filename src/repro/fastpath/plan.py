@@ -4,8 +4,9 @@ A plan is what ``sqnorm_fn`` / ``use_pallas`` used to be: the policy's
 route to accelerated trigger/encode math.  It owns
 
   * the activation decision (``mode="auto"`` → on when running on TPU,
-    interpret-mode parity elsewhere; ``"on"`` forces the plane — what the
-    parity tier and the CPU benchmarks run),
+    except below the dispatch floor and for a round a multi-device mesh
+    partitions; ``"on"`` forces the plane — interpret-mode Pallas off
+    TPU, what the parity tier and the CPU benchmarks run),
   * a cache of :class:`repro.fastpath.layout.FlatLayout` offset tables
     keyed by tree structure (resolved at first trace, static afterwards),
   * the pytree-level ops — each ONE batched Pallas launch over
@@ -99,6 +100,20 @@ class FastPathPlan:
             return True
         W = leaves[0].shape[0]
         return self.layout_for(tree_st).rows * W < SMALL_DISPATCH_ROWS
+
+    def partitioned_by_context(self) -> bool:
+        """True when the surrounding mesh (``jax.set_mesh``) spreads the
+        plane's operands over several devices outside ``shard_map``.
+        Mosaic kernels cannot be partitioned automatically — the compiler
+        refuses them — so ``repro.engine.rounds.policy_rounds`` then
+        takes the jnp oracle.  Inside ``repro.devrun``'s shard_map every
+        axis is manual and the plane runs per device.  Forced plans
+        return False (interpret mode partitions like any jnp code)."""
+        if self.forced:
+            return False
+        mesh = jax.sharding.get_abstract_mesh()
+        return any(size > 1 and kind != jax.sharding.AxisType.Manual
+                   for size, kind in zip(mesh.axis_sizes, mesh.axis_types))
 
     # -- layout -------------------------------------------------------------
 

@@ -31,7 +31,7 @@ from repro.configs.shapes import SHAPES, applicable, input_specs
 from repro.dist import (TrainerConfig, batch_shardings, init_state,
                         make_train_step, tree_shardings)
 from repro.dist.hlo_analysis import collective_bytes
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models import model
 
 POD_SIZE = 256          # devices per pod in the production meshes
@@ -116,7 +116,7 @@ def build_lowerable(cfg, shape_name: str, mesh, workers: int,
 
 def _compile_and_measure(cfg, shape_name: str, mesh, workers: int) -> dict:
     t0 = time.time()
-    with mesh_context(mesh):   # tracing may emit sharding constraints
+    with jax.set_mesh(mesh):   # tracing may emit sharding constraints
         fn, arg_shapes, in_sh, out_sh = build_lowerable(
             cfg, shape_name, mesh, workers)
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)

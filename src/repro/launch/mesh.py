@@ -1,45 +1,21 @@
-"""Production mesh builders (+ jax version compat).
+"""Production mesh builders.
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state — the dry-run sets
-XLA_FLAGS before any jax initialization.
-
-Newer jax (≥0.5) spells the explicit-sharding world ``jax.make_mesh(...,
-axis_types=...)`` + ``jax.set_mesh``; the container's 0.4.x spells it
-``jax.make_mesh(...)`` + the ``Mesh`` context manager.  ``make_mesh`` /
-``mesh_context`` below paper over the difference so every launcher, example
-and subprocess test runs on both.
+XLA_FLAGS before any jax initialization.  Every mesh here uses Auto axis
+types, so bare-PartitionSpec sharding constraints resolve inside jit under
+``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _auto(n):
-    """axis_types tuple for ``jax.make_mesh`` on jax ≥0.5; None on older
-    jax (which has no AxisType and no axis_types kwarg)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return (at.Auto,) * n if at is not None else None
-
-
-def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` with Auto axis types when the
-    installed jax supports them."""
-    types = _auto(len(axes))
-    if types is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=types)
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists, else the Mesh context
-    manager — both make bare-PartitionSpec sharding constraints resolvable
-    inside jit."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.configs.shapes import applicable
+from repro.launch.cache import enable_compile_cache
 from repro.models import model
 
 
@@ -31,6 +32,7 @@ def main(argv=None):
                    help="request batches to serve")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -4,7 +4,7 @@
       --algo lag-wk --steps 200 --batch 32 --seq 256 --workers 8 \\
       --hetero 0.8 --cluster hetero:8@10ms/1Gbps
 
-Runs on whatever devices exist (1 CPU here; the TPU mesh via --mesh prod).
+Runs on whatever devices the process sees (the TPU mesh via --mesh prod).
 Logs loss + LAG communication counters; checkpoints include LAG state.
 ``--hetero`` dials the worker shards' data heterogeneity
 (``repro.netsim.hetero``), ``--cluster`` prices the run's upload mask
@@ -26,7 +26,10 @@ selection, and ``--cluster`` prices the cohort uploads per-client via
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import time
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,10 +39,33 @@ from repro import metrics as metrics_lib
 from repro.checkpoint import save, restore, latest_step
 from repro.configs import get_config
 from repro.data import TokenStream, make_heterogeneous_inputs, make_inputs
-from repro.dist import (TrainerConfig, init_state, lag_trainer,
-                        make_train_step, tree_shardings, batch_shardings)
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               mesh_context)
+from repro.dist import (TrainerConfig, lag_trainer, make_train_step,
+                        tree_shardings, batch_shardings)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.models import model
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """What one launcher run did: its final state, one loss per step, the
+    upload count against GD's, and host-clock step times that end in
+    ``block_until_ready``."""
+    state: Dict
+    losses: np.ndarray
+    comm_masks: np.ndarray       # (steps, units) bool upload decisions
+    uploads: int
+    gd_uploads: int
+    first_step_seconds: float    # compilation + the first step
+    step_seconds: float          # mean of the later steps (nan if none)
+    step_fn: Any                 # the jitted step
+    arg_specs: Tuple             # shapes and shardings of its first call
+    mesh: Any                    # the context mesh it was traced under
+
+    def compiled(self):
+        """The step as compiled for the run (a hit in jit's own cache)."""
+        with jax.set_mesh(self.mesh):
+            return self.step_fn.lower(*self.arg_specs).compile()
 
 
 def build_argparser():
@@ -102,8 +128,23 @@ def build_argparser():
     return p
 
 
-def main(argv=None):
+def _init_on_mesh(key, cfg, tcfg, topo, mesh) -> Dict:
+    """``init_state`` with the W-stacked mirrors built in place on the
+    mesh: a full-width model's mirrors do not fit on one device."""
+    build = functools.partial(lag_trainer.state_for_params, tcfg=tcfg,
+                              topology=topo)
+    params = model.init(key, cfg)
+    return jax.jit(build, out_shardings=tree_shardings(
+        jax.eval_shape(build, params), mesh))(params)
+
+
+def _spec(x):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+
+
+def main(argv=None) -> TrainRun:
     args = build_argparser().parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -128,6 +169,11 @@ def main(argv=None):
                              mesh=mesh, churn=args.fleet_churn,
                              selection=args.fleet_selection)
     graph = getattr(topo, "name", None) == "graph"
+    devices = getattr(topo, "name", None) == "devices"
+    if devices:
+        # the workers' own 1-D mesh is the context shard_map runs under,
+        # and batches shard along it
+        mesh = topo.device_mesh(args.workers)
     # W = batch-shard count: the cohort size for fleet, the node count
     # for graph, the topology's unit count otherwise (--workers default).
     W = topo.units(args.workers) if topo is not None else args.workers
@@ -141,7 +187,6 @@ def main(argv=None):
         make_cluster(args.cluster,
                      num_workers=topo.population if fleet else units)
 
-    devices = getattr(topo, "name", None) == "devices"
     if fleet:
         from repro import fleet as fleet_lib
         state = fleet_lib.init_fleet_state(
@@ -166,14 +211,14 @@ def main(argv=None):
         train_step = graph_lib.make_graph_step(cfg, tcfg, topo,
                                                schedule_seed=args.seed)
     else:
-        state = init_state(jax.random.PRNGKey(args.seed), cfg, tcfg,
-                           topology=topo)
+        state = _init_on_mesh(jax.random.PRNGKey(args.seed), cfg, tcfg,
+                              topo, mesh)
         train_step = make_train_step(cfg, tcfg, topology=topo)
     start = 0
     if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
         state, start = restore(args.ckpt_dir, state)
         print(f"resumed from step {start}")
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if not (devices or graph):
             state_sh = tree_shardings(state, mesh)
             state = jax.device_put(state, state_sh)
@@ -182,7 +227,8 @@ def main(argv=None):
         stream = TokenStream(vocab=cfg.vocab_size, seed=args.seed)
         log = metrics_lib.Logger(args.log)
         t0 = time.time()
-        masks, cohorts, cohort_comm = [], [], []
+        t_first, t_steady, arg_specs = float("nan"), None, None
+        losses, masks, cohorts, cohort_comm = [], [], [], []
         for step in range(start, args.steps):
             if args.hetero is not None:
                 batch = make_heterogeneous_inputs(
@@ -191,15 +237,19 @@ def main(argv=None):
             else:
                 batch = make_inputs(cfg, stream, step, args.batch, args.seq)
             batch = jax.device_put(batch, batch_shardings(batch, mesh))
+            if arg_specs is None:
+                arg_specs = jax.tree_util.tree_map(_spec, (state, batch))
             state, m = step_fn(state, batch)
-            if args.cluster is not None:
-                if fleet:
-                    cohorts.append(
-                        np.asarray(jax.device_get(m["cohort_ids"])))
-                    cohort_comm.append(
-                        np.asarray(jax.device_get(m["cohort_comm"])))
-                else:
-                    masks.append(np.asarray(jax.device_get(m["comm_mask"])))
+            losses.append(m["loss"])
+            masks.append(m["comm_mask"])
+            if t_steady is None:
+                jax.block_until_ready(state)
+                t_first = time.time() - t0
+                t_steady = time.time()
+            if args.cluster is not None and fleet:
+                cohorts.append(np.asarray(jax.device_get(m["cohort_ids"])))
+                cohort_comm.append(
+                    np.asarray(jax.device_get(m["cohort_comm"])))
             if step % 10 == 0 or step == args.steps - 1:
                 log.log(step, loss=m["loss"],
                         comm_round=m["comm_this_round"],
@@ -207,16 +257,21 @@ def main(argv=None):
             if args.ckpt_every and args.ckpt_dir \
                     and (step + 1) % args.ckpt_every == 0:
                 save(args.ckpt_dir, step + 1, state)
+        jax.block_until_ready(state)
         dt = time.time() - t0
-        total = int(jax.device_get(state["lag"]["comm_total"]))
         rounds = args.steps - start
+        step_s = ((time.time() - t_steady) / (rounds - 1) if rounds > 1
+                  else float("nan"))
+        total = int(jax.device_get(state["lag"]["comm_total"]))
+        masks = np.asarray(jax.device_get(masks), bool)
         # GD baseline: every lazy unit uploads every round — the whole
         # COHORT for fleet (the round only polls k of N clients), every
         # directed EDGE for graph, every worker otherwise
-        print(f"done: {rounds} rounds in {dt:.1f}s | uploads {total} "
-              f"vs GD {rounds * units} "
+        print(f"done: {rounds} rounds in {dt:.1f}s (first step incl. "
+              f"compile {t_first:.1f}s, then {step_s:.3f}s/step) | uploads "
+              f"{total} vs GD {rounds * units} "
               f"({100.0 * total / max(rounds * units, 1):.1f}% of GD)")
-        if args.cluster is not None and (masks or cohorts):
+        if args.cluster is not None and rounds:
             from repro.netsim import (make_cluster, price_cohort_mask,
                                       price_edge_mask, price_mask)
             byte_tmpl = state["params"]
@@ -240,21 +295,26 @@ def main(argv=None):
             elif graph:
                 cl = make_cluster(args.cluster, num_workers=units)
                 dst = np.asarray(topo.spec.edge_dst)
-                t_run = price_edge_mask(np.stack(masks), bpu, cl, dst,
+                t_run = price_edge_mask(masks, bpu, cl, dst,
                                         dense_bytes=dense).sum()
                 t_gd = price_edge_mask(np.ones((rounds, units), bool),
                                        dense, cl, dst,
                                        dense_bytes=dense).sum()
             else:
                 cl = make_cluster(args.cluster, num_workers=W)
-                t_run = price_mask(np.stack(masks), bpu, cl,
+                t_run = price_mask(masks, bpu, cl,
                                    dense_bytes=dense).sum()
                 t_gd = price_mask(np.ones((rounds, W), bool), dense, cl,
                                   dense_bytes=dense).sum()
             print(f"simulated wall-clock on '{args.cluster}': "
                   f"{t_run:.2f}s vs GD {t_gd:.2f}s "
                   f"({t_gd / max(t_run, 1e-12):.2f}x advantage)")
-    return state
+    return TrainRun(state=state,
+                    losses=np.asarray(jax.device_get(losses), np.float32),
+                    comm_masks=masks,
+                    uploads=total, gd_uploads=rounds * units,
+                    first_step_seconds=t_first, step_seconds=step_s,
+                    step_fn=step_fn, arg_specs=arg_specs, mesh=mesh)
 
 
 if __name__ == "__main__":

@@ -1,7 +1,7 @@
 """repro.devrun — real multi-device rounds.
 
 In-process tests cover what a 1-CPU pytest process can see: the
-``devices:D`` topology grammar, the documented fallback, the packed
+``devices:D`` topology grammar, the error on too few devices, the packed
 wire format's bitwise pack→gather→unpack→sum equivalence with the
 in-process reduction, and the trace-time wire accounting.  Everything
 that needs real devices spawns a subprocess with
@@ -41,7 +41,7 @@ def _run_py(code: str, devices: int = 8) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Topology registry + fallback (in-process, 1 CPU device)
+# Topology registry + too few devices (in-process, 1 CPU device)
 # ---------------------------------------------------------------------------
 
 def test_devices_topology_grammar():
@@ -69,30 +69,20 @@ def test_devices_mesh_shape_matches_unit_count():
 
 
 def test_fallback_builders_match_sync_trainer(tiny_cfg):
-    """On a process without the devices, the devrun builders take the
-    documented fallback — the vmapped sync step, same trajectory."""
-    from repro.data import TokenStream, make_heterogeneous_inputs
+    """There is no fallback: on a process without the D devices, both
+    devrun builders (and so Experiment and launch.train) raise, naming D
+    and the devices found, instead of quietly running one device."""
     from repro.dist import lag_trainer
 
-    cfg = tiny_cfg
     tcfg = lag_trainer.TrainerConfig(algo="lag-wk", num_workers=4, lr=0.05)
-    stream = TokenStream(vocab=cfg.vocab_size, seed=0)
-    batch = make_heterogeneous_inputs(cfg, stream, 0, 4, 8, 16)
     topo = make_topology("devices:4")
     assert not topo.available(4)
-
-    s_ref = lag_trainer.init_state(jax.random.PRNGKey(0), cfg, tcfg)
-    step_ref = jax.jit(lag_trainer.make_train_step(cfg, tcfg))
-    s_dev = devrun.init_device_state(jax.random.PRNGKey(0), cfg, tcfg,
-                                     topology=topo)
-    step_dev = jax.jit(devrun.make_device_step(cfg, tcfg, topology=topo))
-    for _ in range(3):
-        s_ref, m_ref = step_ref(s_ref, batch)
-        s_dev, m_dev = step_dev(s_dev, batch)
-    np.testing.assert_array_equal(np.asarray(m_ref["comm_mask"]),
-                                  np.asarray(m_dev["comm_mask"]))
-    np.testing.assert_array_equal(float(m_ref["loss"]),
-                                  float(m_dev["loss"]))
+    found = f"sees {len(jax.devices())}"
+    with pytest.raises(RuntimeError, match=f"devices:4 needs 4 devices.*{found}"):
+        devrun.init_device_state(jax.random.PRNGKey(0), tiny_cfg, tcfg,
+                                 topology=topo)
+    with pytest.raises(RuntimeError, match=f"devices:4 needs 4 devices.*{found}"):
+        devrun.make_device_step(tiny_cfg, tcfg, topology=topo)
 
 
 def test_make_device_step_rejects_foreign_topology(tiny_cfg):

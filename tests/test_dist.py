@@ -29,7 +29,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.data import TokenStream, make_heterogeneous_inputs
 from repro.dist import TrainerConfig, init_state, make_train_step, tree_shardings, batch_shardings
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 
 cfg = get_config("llama3.2-1b").reduced(dtype="float32", param_dtype="float32")
 tcfg = TrainerConfig(algo="lag-wk", num_workers=4, lr=0.05)
@@ -46,7 +46,7 @@ for _ in range(3):
 
 # sharded over a (4,2) data×model mesh
 mesh = make_mesh((4, 2), ("data", "model"))
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     s_sh = jax.device_put(init_state(jax.random.PRNGKey(0), cfg, tcfg),
                           tree_shardings(init_state(jax.random.PRNGKey(0), cfg, tcfg), mesh))
     b_sh = jax.device_put(batch, batch_shardings(batch, mesh))
@@ -72,7 +72,7 @@ from repro.configs import get_config
 from repro.data import TokenStream, make_heterogeneous_inputs
 from repro.dist.lag_trainer import TrainerConfig
 from repro.dist import pod_lag
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("llama3.2-1b").reduced()
@@ -81,7 +81,7 @@ state = pod_lag.init_state(jax.random.PRNGKey(0), cfg, tcfg, n_pods=2)
 step = jax.jit(pod_lag.make_pod_lag_step(cfg, tcfg, mesh), donate_argnums=(0,))
 stream = TokenStream(vocab=cfg.vocab_size, seed=0)
 batch = make_heterogeneous_inputs(cfg, stream, 0, 2, 16, 128)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     losses = []
     for _ in range(40):
         state, m = step(state, batch)
@@ -106,7 +106,7 @@ from repro.configs.shapes import input_specs
 from repro.data import TokenStream, make_heterogeneous_inputs
 from repro.dist.lag_trainer import TrainerConfig
 from repro.dist import pod_lag
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("llama3.2-1b").reduced()
@@ -115,7 +115,7 @@ state = pod_lag.init_state(jax.random.PRNGKey(0), cfg, tcfg, n_pods=2)
 stream = TokenStream(vocab=cfg.vocab_size, seed=0)
 batch = make_heterogeneous_inputs(cfg, stream, 0, 2, 8, 64)
 step = pod_lag.make_pod_lag_step(cfg, tcfg, mesh)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     txt = jax.jit(step).lower(state, batch).compile().as_text()
 # find a conditional whose true-branch computation contains an all-reduce
 assert "conditional" in txt, "no conditional in HLO"

@@ -28,11 +28,11 @@ GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
 POLICY_SPECS = ("gd", "lag-wk", "lag-ps", "laq@3", "lasg-wk",
                 "cyc-iag", "num-iag", "cyc-laq@3")
 SERVER_SPECS = ("sgd", "momentum@0.9", "adam", "prox-l1@0.001")
-# devices:2 exercises repro.devrun's builders; on the 1-CPU test process
-# they take the documented fallback (the vmapped sync step — same math),
-# and tests/test_devrun.py covers the real multi-device path in
-# subprocesses with forced host devices
-DEEP_TOPOLOGY_SPECS = ("shards", "pods:2", "async:2@1", "devices:2",
+# devices:1 runs repro.devrun's real shard_map plane on the one CPU
+# device of the test process (devices:D with fewer devices raises);
+# tests/test_devrun.py covers D > 1 in subprocesses with forced host
+# devices
+DEEP_TOPOLOGY_SPECS = ("shards", "pods:2", "async:2@1", "devices:1",
                        "fleet:64@8", "graph:2@complete")
 
 
@@ -40,9 +40,11 @@ def _deep_matrix_dims(topology):
     """(batch, mask_width) for one deep-matrix cell: fleet splits the
     batch over its 8 cohort slots and reports an (N,)-wide population
     mask; graph:2@complete reports a mask per DIRECTED edge (E = 2);
-    everything else runs 2 workers."""
+    devices:1 runs its one worker; everything else runs 2 workers."""
     if topology.startswith("fleet"):
         return 8, 64
+    if topology.startswith("devices"):
+        return 4, 1
     return 4, 2
 
 

@@ -112,7 +112,6 @@ def test_parser_against_real_compiled_8device_hlo():
     code = """
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.launch.mesh import make_mesh
 from repro.dist.hlo_analysis import collective_bytes
 
@@ -124,8 +123,8 @@ def body(x, y):
     s = jax.lax.psum(y, "w")                       # f32[64] all-reduce
     return g.astype(jnp.float32).sum() + s.sum()
 
-f = shard_map(body, mesh=mesh, in_specs=(P("w"), P("w")),
-              out_specs=P(), check_rep=False)
+f = jax.shard_map(body, mesh=mesh, in_specs=(P("w"), P("w")),
+                  out_specs=P(), check_vma=False)
 x = jnp.zeros((D * 256, 128), jnp.uint8)
 y = jnp.zeros((D * 64,), jnp.float32)
 hlo = jax.jit(f).lower(x, y).compile().as_text()
