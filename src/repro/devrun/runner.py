@@ -96,29 +96,22 @@ def init_device_state(key, cfg: ModelConfig, tcfg, policy=None, server=None,
     shared state (params, aggregate ∇, history, opt state) is
     replicated.  Placement at init (rather than reshard-on-entry every
     step) is what lets ``donate_argnums`` actually reuse the buffers:
-    donated input and output shardings match from round 0.  The
-    per-worker state is built in place, shard by shard: the D-stacked
-    mirrors of a full-width model do not fit on one device.  The
-    parameters come from the same eager ``model.init`` as every other
-    topology.  Raises when the process lacks the devices.
+    donated input and output shardings match from round 0.  The state is
+    built in place (``lag_trainer.init_state``'s ``shardings``).  Raises
+    when the process lacks the devices.
     """
     policy, server, topology = _resolve(cfg, tcfg, policy, server, topology)
     mesh = topology.device_mesh(tcfg.num_workers)
     per_worker = set(policy.state_keys) | {"comm_per_worker", "L_m"}
-
-    def build(params):
-        return lag_trainer.state_for_params(params, tcfg, policy=policy,
-                                            server=server, topology=topology)
 
     def placement(path, _):
         keys = [getattr(k, "key", None) for k in path[:2]]
         worker_dim = keys[0] == "lag" and keys[1] in per_worker
         return NamedSharding(mesh, P("workers") if worker_dim else P())
 
-    params = model.init(key, cfg)
-    shardings = jax.tree_util.tree_map_with_path(
-        placement, jax.eval_shape(build, params))
-    return jax.jit(build, out_shardings=shardings)(params)
+    return lag_trainer.init_state(
+        key, cfg, tcfg, policy=policy, server=server, topology=topology,
+        shardings=lambda st: jax.tree_util.tree_map_with_path(placement, st))
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,7 @@ placement comes from the ``PodMesh`` topology's sharding constraints).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -187,12 +188,23 @@ class TrainerConfig:
 # ---------------------------------------------------------------------------
 
 def init_state(key, cfg: ModelConfig, tcfg: TrainerConfig,
-               policy=None, server=None, topology=None) -> Dict:
+               policy=None, server=None, topology=None,
+               shardings=None) -> Dict:
     """Fresh trainer state.  ``grad_hat`` starts at zero with an empty
     history, so round 0 triggers every worker (lhs ‖∇L_m‖² > rhs 0) and
-    delivers the exact first GD step — the paper's all-upload init."""
-    return state_for_params(model.init(key, cfg), tcfg, policy=policy,
-                            server=server, topology=topology)
+    delivers the exact first GD step — the paper's all-upload init.
+
+    ``shardings`` maps the state's abstract tree to its shardings; the
+    state is then built in place, shard by shard, around parameters from
+    the same eager ``model.init``: a full-width model's W-stacked mirrors
+    do not fit on one device."""
+    params = model.init(key, cfg)
+    build = functools.partial(state_for_params, tcfg=tcfg, policy=policy,
+                              server=server, topology=topology)
+    if shardings is None:
+        return build(params)
+    return jax.jit(build, out_shardings=shardings(
+        jax.eval_shape(build, params)))(params)
 
 
 def state_for_params(params, tcfg: TrainerConfig, policy=None, server=None,

@@ -210,9 +210,19 @@ class FastPathPlan:
             out = (zt, zt, jnp.zeros((W,), jnp.float32))
             return out + (jnp.zeros((W, lo.num_leaves), jnp.float32),) \
                 if return_steps else out
-        fg = lo.flatten_stacked(g_st)
-        fq = lo.flatten_stacked(q_st)
-        fe = lo.flatten_stacked(e_st)
+        payload, resid, sq, steps = self.laq_encode_flat(
+            lo, lo.flatten_stacked(g_st), lo.flatten_stacked(q_st),
+            lo.flatten_stacked(e_st), bits=bits)
+        out = (lo.unflatten_stacked(payload, like=jnp.float32),
+               lo.unflatten_stacked(resid, like=jnp.float32),
+               self._total(sq, lo))
+        return out + (steps,) if return_steps else out
+
+    def laq_encode_flat(self, lo: FlatLayout, fg: jnp.ndarray,
+                        fq: jnp.ndarray, fe: jnp.ndarray, *, bits: int):
+        """:meth:`laq_encode` on flat ``(W, rows, LANES)`` buffers of
+        layout ``lo``: (payload, residual, ‖p‖² per-sub-block partials,
+        ``(W, num_leaves)`` steps)."""
         parts = kernels.absmax_blocks(fg, fq, fe, interpret=self.interpret)
         scales = self._per_leaf(parts, lo, "max")          # (W, num_leaves)
         # divide ONCE: this per-leaf step array both feeds the kernel
@@ -222,10 +232,7 @@ class FastPathPlan:
         steps_subs = steps[:, jnp.asarray(lo.sub_leaf)]
         payload, resid, sq = kernels.laq_encode_blocks(
             fg, fq, fe, steps_subs, bits, interpret=self.interpret)
-        out = (lo.unflatten_stacked(payload, like=jnp.float32),
-               lo.unflatten_stacked(resid, like=jnp.float32),
-               self._total(sq, lo))
-        return out + (steps,) if return_steps else out
+        return payload, resid, sq, steps
 
     def _masked(self, a: Pytree, b_st: Pytree, mask: jnp.ndarray, mode: str,
                 a_stacked: bool) -> Pytree:

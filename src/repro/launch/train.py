@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import functools
 import time
 from typing import Any, Dict, Tuple
 
@@ -43,7 +42,6 @@ from repro.dist import (TrainerConfig, lag_trainer, make_train_step,
                         tree_shardings, batch_shardings)
 from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.models import model
 
 
 @dataclasses.dataclass
@@ -128,16 +126,6 @@ def build_argparser():
     return p
 
 
-def _init_on_mesh(key, cfg, tcfg, topo, mesh) -> Dict:
-    """``init_state`` with the W-stacked mirrors built in place on the
-    mesh: a full-width model's mirrors do not fit on one device."""
-    build = functools.partial(lag_trainer.state_for_params, tcfg=tcfg,
-                              topology=topo)
-    params = model.init(key, cfg)
-    return jax.jit(build, out_shardings=tree_shardings(
-        jax.eval_shape(build, params), mesh))(params)
-
-
 def _spec(x):
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
@@ -211,17 +199,21 @@ def main(argv=None) -> TrainRun:
         train_step = graph_lib.make_graph_step(cfg, tcfg, topo,
                                                schedule_seed=args.seed)
     else:
-        state = _init_on_mesh(jax.random.PRNGKey(args.seed), cfg, tcfg,
-                              topo, mesh)
+        state = lag_trainer.init_state(
+            jax.random.PRNGKey(args.seed), cfg, tcfg, topology=topo,
+            shardings=lambda st: tree_shardings(st, mesh))
         train_step = make_train_step(cfg, tcfg, topology=topo)
     start = 0
-    if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
+    resumed = bool(args.resume and args.ckpt_dir
+                   and latest_step(args.ckpt_dir) is not None)
+    if resumed:
         state, start = restore(args.ckpt_dir, state)
         print(f"resumed from step {start}")
     with jax.set_mesh(mesh):
-        if not (devices or graph):
-            state_sh = tree_shardings(state, mesh)
-            state = jax.device_put(state, state_sh)
+        # the shards state is built on the mesh; fleet state and a
+        # restored checkpoint arrive unplaced
+        if fleet or (resumed and not (devices or graph)):
+            state = jax.device_put(state, tree_shardings(state, mesh))
         step_fn = jax.jit(train_step, donate_argnums=(0,))
 
         stream = TokenStream(vocab=cfg.vocab_size, seed=args.seed)
