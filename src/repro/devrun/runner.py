@@ -128,7 +128,10 @@ def make_device_step(cfg: ModelConfig, tcfg, policy=None, server=None,
     arrays, gathered and decoded into worker-order f32 summands.  The
     server half (``engine.rounds.finish_round``) runs replicated outside
     the shard_map, so metrics/counters/history match the in-process
-    topologies exactly.
+    topologies exactly.  Each part of the step runs under one of the
+    round's stages (``repro.engine.rounds.STAGES``): the gathers, their
+    ``lax.cond`` and the sum are ``lag.gather``, the unpack inside it
+    ``lag.wire``.
     """
     policy, server, topology = _resolve(cfg, tcfg, policy, server, topology)
     mesh = topology.device_mesh(tcfg.num_workers)
@@ -150,36 +153,39 @@ def make_device_step(cfg: ModelConfig, tcfg, policy=None, server=None,
 
         def shard_body(pst_m, L_m, shards_m, params, hist, k_idx, key):
             # this device's worker: every leading per-worker dim is 1
-            losses, grads = jax.vmap(
-                lambda b: jax.value_and_grad(
-                    lambda p: model.loss_fn(p, cfg, b))(params))(shards_m)
-            gah = None
-            if policy.needs_grad_at_hat:
-                gah = jax.vmap(
-                    lambda th, b: jax.grad(
-                        lambda p: model.loss_fn(p, cfg, b))(th),
-                    in_axes=(0, 0))(pst_m["theta_hat"], shards_m)
+            with engine_rounds.stage("grad"):
+                losses, grads = jax.vmap(
+                    lambda b: jax.value_and_grad(
+                        lambda p: model.loss_fn(p, cfg, b))(params))(shards_m)
+                gah = None
+                if policy.needs_grad_at_hat:
+                    gah = jax.vmap(
+                        lambda th, b: jax.grad(
+                            lambda p: model.loss_fn(p, cfg, b))(th),
+                        in_axes=(0, 0))(pst_m["theta_hat"], shards_m)
             local = dict(pst_m, hist=hist, L_m=L_m)
             comm, _delta, new_pst, wire = engine_rounds.policy_rounds(
                 policy, lagcfg, params, grads, local, grad_at_hat=gah,
                 step=k_idx, key=key,
                 worker_offset=jax.lax.axis_index("workers"),
                 wire_layout=layout)
-            gmask = jax.lax.all_gather(comm, "workers", tiled=True)  # (W,)
 
             def gather_sum(w):
                 gw = {k: jax.lax.all_gather(v, "workers", tiled=True)
                       for k, v in w.items()}
-                buf = policy.wire_unpack(layout, gw)    # (W, rows, LANES)
+                with engine_rounds.stage("wire"):
+                    buf = policy.wire_unpack(layout, gw)  # (W, rows, LANES)
                 return jnp.sum(buf, axis=0)             # worker order
 
-            # the pod-LAG move at device scale: the payload gather only
-            # exists on the any-triggered branch — an all-quiet round
-            # moves nothing but the mask
-            sum_flat = jax.lax.cond(
-                jnp.any(gmask), gather_sum,
-                lambda w: jnp.zeros((layout.rows, LANES), jnp.float32),
-                wire)
+            with engine_rounds.stage("gather"):
+                gmask = jax.lax.all_gather(comm, "workers", tiled=True)
+                # the pod-LAG move at device scale: the payload gather
+                # only exists on the any-triggered branch — an all-quiet
+                # round moves nothing but the (W,) mask
+                sum_flat = jax.lax.cond(
+                    jnp.any(gmask), gather_sum,
+                    lambda w: jnp.zeros((layout.rows, LANES), jnp.float32),
+                    wire)
             sum_delta = layout.unflatten(sum_flat, like=jnp.float32)
             return gmask, losses, new_pst, sum_delta
 
@@ -191,13 +197,15 @@ def make_device_step(cfg: ModelConfig, tcfg, policy=None, server=None,
             check_vma=False,
         )(pst, L_arr, shards, params, lag_state["hist"], state["step"], key)
 
-        loss = server.composite_loss(jnp.mean(losses), params)
+        with engine_rounds.stage("server"):
+            loss = server.composite_loss(jnp.mean(losses), params)
         new_params, new_opt, new_lag, metrics = engine_rounds.finish_round(
             policy, server, lagcfg, params=params,
             opt_state=state.get("opt"), lag_state=lag_state, comm=gmask,
             sum_delta=sum_delta, new_pst=new_pst, step=state["step"])
-        new_state = dict(state, params=new_params, lag=new_lag,
-                         step=state["step"] + 1)
+        with engine_rounds.stage("server"):
+            new_state = dict(state, params=new_params, lag=new_lag,
+                             step=state["step"] + 1)
         if new_opt is not None:
             new_state["opt"] = new_opt
         metrics["loss"] = loss

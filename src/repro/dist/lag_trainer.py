@@ -265,7 +265,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainerConfig,
     time.  ``schedule_seed``
     seeds the per-round keys of stochastic schedule policies (num-IAG);
     it is deterministic in the step counter, so no RNG state needs
-    checkpointing.
+    checkpointing.  Each part of the step runs under one of the round's
+    stages (``repro.engine.rounds.STAGES``).
     """
     policy = policy if policy is not None else tcfg.comm_policy()
     server = server if server is not None else tcfg.server_optimizer()
@@ -278,30 +279,34 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainerConfig,
         # n_pods); for the flat trainer it equals tcfg.num_workers
         W = lag_state["comm_per_worker"].shape[0]
         lagcfg = tcfg.lag_config(num_units=W)
-        shards = topology.place_batch(batch, W)
 
         # async topologies hand each worker the params it LAST SAW
         # (θ^{k−s_m}); sync topologies return None and every worker's
         # backward pass runs at the shared θ^k — a trace-time branch
-        views = topology.worker_views(params, lag_state, W)
-        if views is None:
-            losses, grads = jax.vmap(
-                lambda b: jax.value_and_grad(
-                    lambda p: model.loss_fn(p, cfg, b))(params))(shards)
-        else:
-            losses, grads = jax.vmap(
-                lambda th, b: jax.value_and_grad(
-                    lambda p: model.loss_fn(p, cfg, b))(th))(views, shards)
-        loss = server.composite_loss(jnp.mean(losses), params)
+        with engine_rounds.stage("grad"):
+            shards = topology.place_batch(batch, W)
+            views = topology.worker_views(params, lag_state, W)
+            if views is None:
+                losses, grads = jax.vmap(
+                    lambda b: jax.value_and_grad(
+                        lambda p: model.loss_fn(p, cfg, b))(params))(shards)
+            else:
+                losses, grads = jax.vmap(
+                    lambda th, b: jax.value_and_grad(
+                        lambda p: model.loss_fn(p, cfg, b))(th))(
+                    views, shards)
+        with engine_rounds.stage("server"):
+            loss = server.composite_loss(jnp.mean(losses), params)
 
         grad_at_hat = None
         if policy.needs_grad_at_hat:
             # LASG-WK: ∇ℓ_m(θ̂_m) on the CURRENT shard — a second vmapped
             # backward pass, each worker at its own stale iterate
-            grad_at_hat = jax.vmap(
-                lambda th, b: jax.grad(
-                    lambda p: model.loss_fn(p, cfg, b))(th),
-                in_axes=(0, 0))(lag_state["theta_hat"], shards)
+            with engine_rounds.stage("grad"):
+                grad_at_hat = jax.vmap(
+                    lambda th, b: jax.grad(
+                        lambda p: model.loss_fn(p, cfg, b))(th),
+                    in_axes=(0, 0))(lag_state["theta_hat"], shards)
 
         key = None
         if policy.needs_rng:
@@ -315,12 +320,12 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainerConfig,
             opt_state=state.get("opt"), lag_state=lag_state, grads=grads,
             step=state["step"], grad_at_hat=grad_at_hat, key=key,
             reduce_fn=reduce_fn, theta_view=views)
-        adv = topology.advance_views(new_lag, new_params)
-        if adv:
-            new_lag = dict(new_lag, **adv)
-
-        new_state = dict(state, params=new_params, lag=new_lag,
-                         step=state["step"] + 1)
+        with engine_rounds.stage("server"):
+            adv = topology.advance_views(new_lag, new_params)
+            if adv:
+                new_lag = dict(new_lag, **adv)
+            new_state = dict(state, params=new_params, lag=new_lag,
+                             step=state["step"] + 1)
         if new_opt is not None:
             new_state["opt"] = new_opt
         metrics["loss"] = loss

@@ -22,6 +22,12 @@ pre-engine trainer so checkpoints restore across the refactor):
   L_m                   (W,) per-unit smoothness (PS-rule policies)
   rounds_skipped        optional scalar — advanced when no unit uploads
                         (the pod driver's all-quiet counter)
+
+Stages: each part of the round runs under one ``jax.named_scope`` of
+:data:`STAGES`.  A scope is HLO metadata (``op_name``) only, so the
+compiled step is the same with or without it; a profile reads device
+time per stage from it (``bench/stages.py``).  Where scopes nest, the
+innermost names the op.
 """
 from __future__ import annotations
 
@@ -31,11 +37,38 @@ import jax
 import jax.numpy as jnp
 
 from repro import fastpath as fastpath_lib
-from repro.comm import CommPolicy, CommRound, run_round
+from repro.comm import CommPolicy, CommRound
 from repro.core import lag
 from repro.engine.server import ServerOptimizer
 
 Pytree = Any
+
+#: The round's stages, as ``jax.named_scope`` names:
+#:   lag.grad    the workers' ``value_and_grad`` (LASG's second backward
+#:               too); forward ops read ``jvp(``, backward ops
+#:               ``transpose(jvp(``
+#:   lag.encode  innovation, trigger norms, LAQ absmax/quantize and
+#:               ``should_upload`` (``fast_precompute`` + the vmapped
+#:               trigger, or ``encode`` + ``should_upload``)
+#:   lag.layout  ``FlatLayout``'s flattens and scatters (named in
+#:               ``repro.fastpath.layout``, below the engine)
+#:   lag.fold    ``fast_decode`` / ``decode``: the masked mirror folds
+#:   lag.wire    ``wire_pack`` / ``wire_unpack``
+#:   lag.gather  ``repro.devrun``'s mask and payload all-gathers, their
+#:               ``lax.cond`` and the worker-order sum
+#:   lag.server  the reduction of the masked deltas in process,
+#:               :func:`finish_round` and the step's loss and counter
+STAGES = ("lag.grad", "lag.encode", "lag.layout", "lag.fold", "lag.wire",
+          "lag.gather", "lag.server")
+
+
+def stage(name: str):
+    """The ``jax.named_scope`` of stage ``lag.<name>`` of :data:`STAGES`."""
+    scope = f"lag.{name}"
+    if scope not in STAGES:
+        raise ValueError(f"{scope!r} is not one of the round's stages "
+                         f"{STAGES}")
+    return jax.named_scope(scope)
 
 
 def comm_counter_updates(lag_state: Dict, comm: jnp.ndarray,
@@ -142,22 +175,27 @@ def policy_rounds(policy: CommPolicy, lagcfg: lag.LAGConfig, params: Pytree,
         plan = None
     fast = None
     if plan is not None:
-        fast = policy.fast_precompute(plan, grads, pst, theta=theta_arg,
-                                      theta_stacked=theta_stacked,
-                                      grad_at_hat=grad_at_hat)
+        with stage("encode"):
+            fast = policy.fast_precompute(plan, grads, pst, theta=theta_arg,
+                                          theta_stacked=theta_stacked,
+                                          grad_at_hat=grad_at_hat)
 
     if fast is None:
         def one_worker(g, pst_m, gah_m, lm, wid, theta_m):
+            # repro.comm.run_round, with its halves in their stages
             ctx = CommRound(theta=theta_m, grad_new=g, hist=hist, cfg=lagcfg,
                             L_m=lm, grad_at_hat=gah_m, k=k_idx,
                             worker_id=wid, key=key)
+            with stage("encode"):
+                payload, aux = policy.encode(ctx, pst_m)
+                comm_m = policy.should_upload(ctx, pst_m, payload, aux)
+            with stage("fold"):
+                delta_m, new_st = policy.decode(ctx, pst_m, payload, aux,
+                                                comm_m)
             if wire_layout is None:
-                return run_round(policy, ctx, pst_m)
+                return comm_m, delta_m, new_st
             # wire route keeps payload + aux visible past the decode so
             # the stacked candidate can be packed for the collective
-            payload, aux = policy.encode(ctx, pst_m)
-            comm_m = policy.should_upload(ctx, pst_m, payload, aux)
-            delta_m, new_st = policy.decode(ctx, pst_m, payload, aux, comm_m)
             return comm_m, delta_m, new_st, payload, aux
 
         out = jax.vmap(one_worker, in_axes=(0, 0, 0, 0, 0, th_ax))(
@@ -165,7 +203,8 @@ def policy_rounds(policy: CommPolicy, lagcfg: lag.LAGConfig, params: Pytree,
         if wire_layout is None:
             return out
         comm, delta, new_pst, payload, aux = out
-        wire = policy.wire_pack(wire_layout, payload, aux, comm)
+        with stage("wire"):
+            wire = policy.wire_pack(wire_layout, payload, aux, comm)
         return comm, delta, new_pst, wire
 
     # fast route: encode + trigger stay per-worker (cheap — the heavy
@@ -174,18 +213,22 @@ def policy_rounds(policy: CommPolicy, lagcfg: lag.LAGConfig, params: Pytree,
         ctx = CommRound(theta=theta_m, grad_new=g, hist=hist, cfg=lagcfg,
                         L_m=lm, grad_at_hat=gah_m, k=k_idx, worker_id=wid,
                         key=key, fast=fast_m)
-        payload, aux = policy.encode(ctx, pst_m)
-        return policy.should_upload(ctx, pst_m, payload, aux), payload, aux
+        with stage("encode"):
+            payload, aux = policy.encode(ctx, pst_m)
+            return (policy.should_upload(ctx, pst_m, payload, aux), payload,
+                    aux)
 
     comm, payload, aux = jax.vmap(
         enc_and_trigger, in_axes=(0, 0, 0, 0, 0, th_ax, 0))(
         grads, pst, gah, L_arr, worker_ids, theta_arg, fast)
-    delta, new_pst = policy.fast_decode(plan, pst, payload, aux, comm,
-                                        theta=theta_arg,
-                                        theta_stacked=theta_stacked)
+    with stage("fold"):
+        delta, new_pst = policy.fast_decode(plan, pst, payload, aux, comm,
+                                            theta=theta_arg,
+                                            theta_stacked=theta_stacked)
     if wire_layout is None:
         return comm, delta, new_pst
-    wire = policy.wire_pack(wire_layout, payload, aux, comm)
+    with stage("wire"):
+        wire = policy.wire_pack(wire_layout, payload, aux, comm)
     return comm, delta, new_pst, wire
 
 
@@ -223,7 +266,8 @@ def lag_round(policy: CommPolicy, server: ServerOptimizer,
                                          lag_state, grad_at_hat,
                                          step=step, key=key,
                                          theta_view=theta_view)
-    sum_delta = (reduce_fn or sum_reduce)(comm, delta)
+    with stage("server"):
+        sum_delta = (reduce_fn or sum_reduce)(comm, delta)
     return finish_round(policy, server, lagcfg, params=params,
                         opt_state=opt_state, lag_state=lag_state, comm=comm,
                         sum_delta=sum_delta, new_pst=new_pst, step=step)
@@ -242,39 +286,43 @@ def finish_round(policy: CommPolicy, server: ServerOptimizer,
     (``repro.devrun``) reduces packed wire payloads across real devices
     inside ``shard_map`` — can rejoin the shared round here and stay
     bit-identical with the in-process topologies from this point down.
+    All of it is stage ``lag.server``.
     """
-    # server recursion (eq. 4 aggregate) + the pluggable server step
-    nabla_new = lag.tree_add(lag_state["nabla"], sum_delta)
-    new_params, new_opt = server.apply(params, opt_state, nabla_new, step,
-                                       lagcfg)
-    # iterate-lag entry from the ACTUAL movement (post-prox / post-Adam),
-    # so the trigger RHS always measures what the server really did
-    hist_new = lag.hist_push(
-        lag_state["hist"], lag.tree_sqnorm(lag.tree_sub(new_params, params)))
+    with stage("server"):
+        # server recursion (eq. 4 aggregate) + the pluggable server step
+        nabla_new = lag.tree_add(lag_state["nabla"], sum_delta)
+        new_params, new_opt = server.apply(params, opt_state, nabla_new,
+                                           step, lagcfg)
+        # iterate-lag entry from the ACTUAL movement (post-prox /
+        # post-Adam), so the trigger RHS always measures what the server
+        # really did
+        hist_new = lag.hist_push(
+            lag_state["hist"],
+            lag.tree_sqnorm(lag.tree_sub(new_params, params)))
 
-    comm_i, counters = comm_counter_updates(lag_state, comm)
-    new_lag = dict(lag_state, nabla=nabla_new, hist=hist_new,
-                   **new_pst, **counters)
-    any_comm = jnp.any(comm)
-    if "rounds_skipped" in lag_state:
-        new_lag["rounds_skipped"] = lag_state["rounds_skipped"] \
-            + (1 - any_comm.astype(jnp.int32))
+        comm_i, counters = comm_counter_updates(lag_state, comm)
+        new_lag = dict(lag_state, nabla=nabla_new, hist=hist_new,
+                       **new_pst, **counters)
+        any_comm = jnp.any(comm)
+        if "rounds_skipped" in lag_state:
+            new_lag["rounds_skipped"] = lag_state["rounds_skipped"] \
+                + (1 - any_comm.astype(jnp.int32))
 
-    # policy-declared traffic: ONE upload of the param-shaped gradient
-    # costs wire_bytes (a trace-time constant), so totals are exact
-    # rescalings of the upload counters
-    bytes_per_upload = policy.wire_bytes(params)
-    metrics = {
-        "comm_mask": comm,
-        "comm_this_round": jnp.sum(comm_i),
-        "comm_total": new_lag["comm_total"],
-        "wire_bytes_this_round":
-            jnp.sum(comm_i).astype(jnp.float32) * bytes_per_upload,
-        "wire_bytes_total":
-            new_lag["comm_total"].astype(jnp.float32) * bytes_per_upload,
-        "trigger_rhs": lag.trigger_rhs(lag_state["hist"], lagcfg),
-        "trigger_rhs_underflow":
-            lag.rhs_underflow(lag_state["hist"], lagcfg, step),
-        "skipped_round": (~any_comm).astype(jnp.int32),
-    }
-    return new_params, new_opt, new_lag, metrics
+        # policy-declared traffic: ONE upload of the param-shaped gradient
+        # costs wire_bytes (a trace-time constant), so totals are exact
+        # rescalings of the upload counters
+        bytes_per_upload = policy.wire_bytes(params)
+        metrics = {
+            "comm_mask": comm,
+            "comm_this_round": jnp.sum(comm_i),
+            "comm_total": new_lag["comm_total"],
+            "wire_bytes_this_round":
+                jnp.sum(comm_i).astype(jnp.float32) * bytes_per_upload,
+            "wire_bytes_total":
+                new_lag["comm_total"].astype(jnp.float32) * bytes_per_upload,
+            "trigger_rhs": lag.trigger_rhs(lag_state["hist"], lagcfg),
+            "trigger_rhs_underflow":
+                lag.rhs_underflow(lag_state["hist"], lagcfg, step),
+            "skipped_round": (~any_comm).astype(jnp.int32),
+        }
+        return new_params, new_opt, new_lag, metrics

@@ -27,10 +27,14 @@ buffer (``(W, rows, LANES)`` for stacked per-worker trees); ``sub_leaf``
 is the static leaf-offset table the batched kernels and the fixed-order
 segment reductions consume.  Zero-size leaves occupy zero sub-blocks
 and round-trip as empty arrays.
+
+Every flatten and scatter runs under the round's ``lag.layout`` stage
+(``repro.engine.rounds.STAGES``), also where a caller's stage is open.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import jax
@@ -49,6 +53,17 @@ BLOCK = BLOCK_ROWS * LANES      # elements per grid block
 #: dtypes the flat plane serves; everything is computed in float32 and
 #: scattered back at the leaf's own dtype (the jnp oracle's convention)
 SUPPORTED_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
+
+
+def _layout_stage(method):
+    """Run ``method`` under the ``lag.layout`` scope; the name is one of
+    ``repro.engine.rounds.STAGES``, spelled here because the engine
+    imports this module."""
+    @functools.wraps(method)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("lag.layout"):
+            return method(*args, **kwargs)
+    return scoped
 
 
 def tree_signature(tree: Pytree) -> Tuple:
@@ -130,12 +145,14 @@ class FlatLayout:
                                   jnp.float32))
         return segs
 
+    @_layout_stage
     def flatten(self, tree: Pytree) -> jnp.ndarray:
         """Template-shaped tree → ``(rows, LANES)`` float32 buffer."""
         if self.nblocks == 0:
             return jnp.zeros((0, LANES), jnp.float32)
         return jnp.concatenate(self._flat_leaves(tree, ()), axis=0)
 
+    @_layout_stage
     def flatten_stacked(self, tree: Pytree) -> jnp.ndarray:
         """Stacked ``(W, …leaf)`` tree → ``(W, rows, LANES)`` float32."""
         leaves = jax.tree_util.tree_leaves(tree)
@@ -172,6 +189,7 @@ class FlatLayout:
                                    0, size, axis=ax)
         return seg.reshape(lead + shape).astype(dtype)
 
+    @_layout_stage
     def unflatten(self, buf: jnp.ndarray, like: Any = None) -> Pytree:
         """``(rows, LANES)`` buffer → template tree (leaf dtypes restored)."""
         dts = self._out_dtypes(like)
@@ -179,6 +197,7 @@ class FlatLayout:
                   for i in range(self.num_leaves)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
+    @_layout_stage
     def unflatten_stacked(self, buf: jnp.ndarray, like: Any = None) -> Pytree:
         """``(W, rows, LANES)`` buffer → stacked template tree."""
         W = buf.shape[0]
@@ -217,6 +236,7 @@ class FlatLayout:
         """Columns of the compact ``(W, packed_cols)`` per-client view."""
         return sum(self.leaf_lanes) * LANES
 
+    @_layout_stage
     def pack_stacked(self, tree: Pytree) -> jnp.ndarray:
         """Stacked ``(W, …leaf)`` tree → compact ``(W, packed_cols)``
         float32 — per-leaf LANES padding only, no kernel-grid tail."""
@@ -238,6 +258,7 @@ class FlatLayout:
             return jnp.zeros((W, 0), jnp.float32)
         return jnp.concatenate(segs, axis=1)
 
+    @_layout_stage
     def unpack_stacked(self, buf: jnp.ndarray, like: Any = None) -> Pytree:
         """Compact ``(W, packed_cols)`` buffer → stacked template tree."""
         W = buf.shape[0]

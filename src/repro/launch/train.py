@@ -22,10 +22,18 @@ selection, and ``--cluster`` prices the cohort uploads per-client via
 (``repro.graph`` — per-edge lazy triggers + Metropolis mixing;
 ``--cluster`` is sized to the E directed edges and priced via
 ``price_edge_mask``).
+
+``--profile DIR`` writes a JAX profiler trace of the steps after the
+first (which compiles): each step is a ``train`` step annotation, the
+host spans are ``repro.input`` (the batch and its ``device_put``),
+``repro.log`` (the logger's sync on the loss) and ``repro.ckpt``
+(checkpoint saves), and the program's device ops carry the round's
+``lag.*`` stage scopes (``repro.engine.rounds.STAGES``).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, Tuple
@@ -123,7 +131,24 @@ def build_argparser():
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--ckpt-every", type=int, default=0)
     p.add_argument("--resume", action="store_true")
+    p.add_argument("--profile", default=None, metavar="DIR",
+                   help="write a JAX profiler trace of the steps after the "
+                        "first (which compiles) to DIR: each step is a "
+                        "'train' step annotation, the host spans "
+                        "repro.input, repro.log and repro.ckpt, and the "
+                        "program's device ops carry the round's lag.* "
+                        "stage scopes")
     return p
+
+
+def _span(on: bool, name: str, step: int = None):
+    """A profiler annotation while profiling, else nothing: the step
+    (``step`` given) or the host span ``name``."""
+    if not on:
+        return contextlib.nullcontext()
+    if step is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    return jax.profiler.TraceAnnotation(name)
 
 
 def _spec(x):
@@ -221,35 +246,48 @@ def main(argv=None) -> TrainRun:
         t0 = time.time()
         t_first, t_steady, arg_specs = float("nan"), None, None
         losses, masks, cohorts, cohort_comm = [], [], [], []
+        profiling = False
         for step in range(start, args.steps):
-            if args.hetero is not None:
-                batch = make_heterogeneous_inputs(
-                    cfg, stream, step, W, args.batch, args.seq,
-                    fixed=False, h=args.hetero)
-            else:
-                batch = make_inputs(cfg, stream, step, args.batch, args.seq)
-            batch = jax.device_put(batch, batch_shardings(batch, mesh))
-            if arg_specs is None:
-                arg_specs = jax.tree_util.tree_map(_spec, (state, batch))
-            state, m = step_fn(state, batch)
-            losses.append(m["loss"])
-            masks.append(m["comm_mask"])
-            if t_steady is None:
-                jax.block_until_ready(state)
-                t_first = time.time() - t0
-                t_steady = time.time()
-            if args.cluster is not None and fleet:
-                cohorts.append(np.asarray(jax.device_get(m["cohort_ids"])))
-                cohort_comm.append(
-                    np.asarray(jax.device_get(m["cohort_comm"])))
-            if step % 10 == 0 or step == args.steps - 1:
-                log.log(step, loss=m["loss"],
-                        comm_round=m["comm_this_round"],
-                        comm_total=m["comm_total"])
-            if args.ckpt_every and args.ckpt_dir \
-                    and (step + 1) % args.ckpt_every == 0:
-                save(args.ckpt_dir, step + 1, state)
+            if args.profile and step == start + 1:
+                jax.profiler.start_trace(args.profile)
+                profiling = True
+            with _span(profiling, "train", step=step):
+                with _span(profiling, "repro.input"):
+                    if args.hetero is not None:
+                        batch = make_heterogeneous_inputs(
+                            cfg, stream, step, W, args.batch, args.seq,
+                            fixed=False, h=args.hetero)
+                    else:
+                        batch = make_inputs(cfg, stream, step, args.batch,
+                                            args.seq)
+                    batch = jax.device_put(batch,
+                                           batch_shardings(batch, mesh))
+                if arg_specs is None:
+                    arg_specs = jax.tree_util.tree_map(_spec, (state, batch))
+                state, m = step_fn(state, batch)
+                losses.append(m["loss"])
+                masks.append(m["comm_mask"])
+                if t_steady is None:
+                    jax.block_until_ready(state)
+                    t_first = time.time() - t0
+                    t_steady = time.time()
+                if args.cluster is not None and fleet:
+                    cohorts.append(
+                        np.asarray(jax.device_get(m["cohort_ids"])))
+                    cohort_comm.append(
+                        np.asarray(jax.device_get(m["cohort_comm"])))
+                if step % 10 == 0 or step == args.steps - 1:
+                    with _span(profiling, "repro.log"):
+                        log.log(step, loss=m["loss"],
+                                comm_round=m["comm_this_round"],
+                                comm_total=m["comm_total"])
+                if args.ckpt_every and args.ckpt_dir \
+                        and (step + 1) % args.ckpt_every == 0:
+                    with _span(profiling, "repro.ckpt"):
+                        save(args.ckpt_dir, step + 1, state)
         jax.block_until_ready(state)
+        if profiling:
+            jax.profiler.stop_trace()
         dt = time.time() - t0
         rounds = args.steps - start
         step_s = ((time.time() - t_steady) / (rounds - 1) if rounds > 1

@@ -86,3 +86,23 @@ def test_train_devices_too_few_raises(no_cache):
     with pytest.raises(RuntimeError, match=f"devices:{D} needs {D} devices"):
         train.main(TINY + ["--topology", f"devices:{D}", "--workers",
                            str(D)])
+
+
+def test_train_profile_names_steps_and_host_spans(no_cache, tmp_path):
+    """``--profile DIR`` traces the steps after the first: the step
+    annotation and the ``repro.*`` host spans are in the trace."""
+    import glob
+    from jax.profiler import ProfileData
+    prof = tmp_path / "prof"
+    train.main(TINY + ["--workers", "2", "--profile", str(prof),
+                       "--ckpt-dir", str(tmp_path / "ck"),
+                       "--ckpt-every", "1"])
+    found = glob.glob(str(prof / "**" / "*.xplane.pb"), recursive=True)
+    assert len(found) == 1
+    events = [(e.name, dict(e.stats))
+              for plane in ProfileData.from_file(found[0]).planes
+              for line in plane.lines for e in line.events]
+    names = {n for n, _ in events}
+    assert {"repro.input", "repro.log", "repro.ckpt"} <= names
+    steps = [st for n, st in events if n == "train"]
+    assert [int(st["step_num"]) for st in steps] == [1]  # step 0 compiles
