@@ -6,14 +6,15 @@
 
 One chip: ``repro.launch.train.main`` trains mamba2-370m (48 layers, d_model
 1024, vocab 50280; random weights from ``--seed``) for a few lag-wk steps with
-W = 2 lazy workers under ``--fastpath auto``, in this process.  It checks that
-the auto route takes the Pallas comm plane (``tpu_custom_call`` in the compiled
-step), that every loss is finite, and then runs the plane's kernels on the
-run's own real-size flat buffers against the jnp oracle
-(``repro.kernels.lag_trigger.ref``).
+W = 2 lazy workers under ``--fastpath auto``, in this process.  The auto plan
+is active there, and lag-wk opts out of it: its trigger and ĝ fold run as XLA
+fusions on the parameter tree, so the compiled step must hold no
+``tpu_custom_call``.  It checks that, that every loss is finite, and then runs
+the plane's kernels on the run's own real-size flat buffers against the jnp
+oracle (``repro.kernels.lag_trigger.ref``).
 
 Four chips: the same model under ``--topology devices:4`` (one lazy worker per
-chip, the Pallas plane on each, packed wire arrays in a ``lax.cond``-gated
+chip, LAQ's Pallas plane on each, packed wire arrays in a ``lax.cond``-gated
 all-gather) for lag-wk and laq@4, each against ``shards`` with ``--workers 4``
 on the same host mesh; laq@4 also against ``devices:4`` with the jnp oracle
 (``--fastpath off``), which isolates the plane from the topology.  Each pair
@@ -108,7 +109,7 @@ def one_chip(devs, seed: int) -> None:
     print(f"model: {ARCH} layers={cfg.num_layers} d_model={cfg.d_model} "
           f"vocab={cfg.vocab_size} params={n_params} | plane buffer "
           f"{lo.rows} rows x 128 per worker, W={W}")
-    # the auto route's two oracle cases must not apply at this size
+    # the auto plan is active at this size, so the route is the policy's
     check(plan.enabled and not plan.interpret,
           "fastpath auto is not compiled-Pallas on this TPU")
     check(plan.supports(stacked), "param dtypes the f32 plane cannot serve")
@@ -133,7 +134,8 @@ def one_chip(devs, seed: int) -> None:
     del compiled
     check(len(run.losses) == STEPS and np.isfinite(run.losses).all(),
           f"non-finite loss: {run.losses}")
-    check(n_custom > 0, "the compiled step holds no Pallas kernel")
+    check(n_custom == 0, "the lag-wk step holds a Pallas kernel: the dense "
+          "policies run their round as XLA fusions")
 
     # the run's own real-size buffers: both workers' ĝ mirrors and θ
     gh, params = run.state["lag"]["grad_hat"], run.state["params"]

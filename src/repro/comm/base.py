@@ -66,9 +66,9 @@ class CommRound:
     key: Optional[jnp.ndarray] = None    # per-round PRNG key, broadcast to
     #                                      every worker (stochastic schedules)
     fast: Optional[Dict[str, Any]] = None    # this worker's slice of the
-    #   batched fast-path precompute (repro.fastpath): kernel-served trigger
-    #   sqnorms / LAQ payloads the policy consumes instead of recomputing
-    #   per leaf.  None on the oracle path.
+    #   batched fast-path precompute (repro.fastpath): the kernel-served LAQ
+    #   payload, residual and trigger LHS the policy consumes instead of
+    #   recomputing per leaf.  None on the oracle path.
 
 
 # ---------------------------------------------------------------------------
@@ -104,6 +104,11 @@ class CommPolicy:
     explicitly; the base method raises, which is the registry tripwire
     against new policies silently bypassing the plane
     (tests/test_engine.py runs the smoke matrix with the plan forced on).
+    Only LAQ serves its round from the plane: its absmax, quantize and
+    residual sweep needs the flat buffer.  The dense family (gd, lag-wk,
+    lag-ps, lasg-wk) returns None: its round is a per-leaf reduce and a
+    masked fold, which XLA fuses on the parameter tree for less HBM
+    traffic than the plane's flatten, kernel and scatter.
     """
     name: str = "base"
     state_keys: Tuple[str, ...] = ("grad_hat",)

@@ -51,20 +51,12 @@ class LAGWKPolicy(CommPolicy):
 
     def should_upload(self, ctx: CommRound, st: PolicyState, payload: Pytree,
                       aux: Dict[str, Any]) -> jnp.ndarray:
-        if ctx.fast is not None and "lhs_sq" in ctx.fast:
-            lhs = ctx.fast["lhs_sq"]      # one batched launch, all workers
-        else:
-            lhs = self.sqnorm_fn(payload)
-        return lhs > lag.trigger_rhs(ctx.hist, ctx.cfg)
+        return self.sqnorm_fn(payload) > lag.trigger_rhs(ctx.hist, ctx.cfg)
 
     def fast_precompute(self, plan, grads, st, *, theta, theta_stacked,
                         grad_at_hat=None):
-        # ‖δ∇‖² of the innovation the encode builds anyway (the same
-        # subtraction, merged by XLA): the plane then flattens ONE
-        # param-sized buffer for both the trigger and the ĝ fold, where
-        # flattening g and ĝ apart costs a third copy in device memory
-        return {"lhs_sq": plan.sqnorm(self.innovation(grads,
-                                                      st["grad_hat"]))}
+        # opt out: XLA fuses ‖δ∇‖² and ĝ + c·δ∇ per leaf, with no flatten
+        return None
 
 
 class LAGPSPolicy(CommPolicy):
@@ -80,18 +72,13 @@ class LAGPSPolicy(CommPolicy):
                       aux: Dict[str, Any]) -> jnp.ndarray:
         if ctx.L_m is None:
             raise ValueError("LAG-PS requires per-worker smoothness L_m")
-        if ctx.fast is not None and "dtheta_sq" in ctx.fast:
-            lhs = (ctx.L_m.astype(jnp.float32) ** 2) * ctx.fast["dtheta_sq"]
-            return lhs > lag.trigger_rhs(ctx.hist, ctx.cfg)
         return lag.ps_communicate(ctx.theta, st["theta_hat"], ctx.L_m,
                                   ctx.hist, ctx.cfg, sqnorm_fn=self.sqnorm_fn)
 
     def fast_precompute(self, plan, grads, st, *, theta, theta_stacked,
                         grad_at_hat=None):
-        # 15b's iterate drift ‖θ̂_m − θ‖² for every worker at once; θ may
-        # be the shared (unstacked) iterate — broadcast in the kernel
-        return {"dtheta_sq": plan.delta_sqnorm(st["theta_hat"], theta,
-                                               b_stacked=theta_stacked)}
+        # opt out: XLA fuses ‖θ̂_m − θ‖² and the θ̂ select per leaf
+        return None
 
 
 class LASGWKPolicy(CommPolicy):
@@ -117,8 +104,6 @@ class LASGWKPolicy(CommPolicy):
 
     def should_upload(self, ctx: CommRound, st: PolicyState, payload: Pytree,
                       aux: Dict[str, Any]) -> jnp.ndarray:
-        if ctx.fast is not None and "lhs_sq" in ctx.fast:
-            return ctx.fast["lhs_sq"] > lag.trigger_rhs(ctx.hist, ctx.cfg)
         if ctx.grad_at_hat is None:
             raise ValueError("LASG-WK requires grad_at_hat (the driver must "
                              "evaluate ∇ℓ_m(θ̂_m) on the current sample)")
@@ -127,8 +112,5 @@ class LASGWKPolicy(CommPolicy):
 
     def fast_precompute(self, plan, grads, st, *, theta, theta_stacked,
                         grad_at_hat=None):
-        if grad_at_hat is None:
-            raise ValueError("LASG-WK requires grad_at_hat (the driver must "
-                             "evaluate ∇ℓ_m(θ̂_m) on the current sample)")
-        # the correlated stochastic trigger: ‖∇ℓ(θ^k;ξ) − ∇ℓ(θ̂;ξ)‖²
-        return {"lhs_sq": plan.delta_sqnorm(grads, grad_at_hat)}
+        # opt out: XLA fuses ‖∇ℓ(θ^k;ξ) − ∇ℓ(θ̂;ξ)‖² and the folds per leaf
+        return None

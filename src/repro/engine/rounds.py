@@ -132,13 +132,14 @@ def policy_rounds(policy: CommPolicy, lagcfg: lag.LAGConfig, params: Pytree,
     topology) broadcasts the shared ``params``.
 
     Fast path: when the policy carries an ACTIVE ``repro.fastpath`` plan,
-    the kernel-served per-round quantities (trigger sqnorms, the LAQ
-    encode) are computed ONCE for all workers — batched flat-buffer
-    Pallas launches — via ``policy.fast_precompute`` before the vmap;
+    the kernel-served per-round quantities (the LAQ encode) are computed
+    ONCE for all workers — batched flat-buffer Pallas launches — via
+    ``policy.fast_precompute`` before the vmap;
     each worker's slice arrives through ``ctx.fast``, and the state fold
     (masked lazy updates) runs batched through ``policy.fast_decode``
     after the vmapped trigger.  Policies with nothing kernel-served
-    (``fast_precompute`` → None) take the plain vmapped round; float64
+    (``fast_precompute`` → None: the dense family) take the plain vmapped
+    round, per-leaf XLA fusions with no flatten; float64
     trees fall back in ``auto`` mode and raise under a forced plan.
     """
     W = jax.tree_util.tree_leaves(grads)[0].shape[0]

@@ -1,8 +1,13 @@
 """``repro.fastpath`` — the batched flat-buffer comm plane.
 
-The per-round trigger/encode hot path (eq. 15a/15b sqnorms, LAQ
-absmax+encode, masked lazy updates) used to launch one Pallas kernel per
-pytree leaf per worker.  This package flattens the gradient pytree ONCE
+LAQ's per-round encode (absmax, then the fused quantize + residual +
+trigger-sqnorm sweep, and the masked folds of q̂ and the residual) used to
+launch one Pallas kernel per pytree leaf per worker.  It is the plane's
+one hot path on TPU: the dense policies (gd, lag-wk, lag-ps, lasg-wk)
+opt out, because their round is a per-leaf reduce and a masked fold that
+XLA fuses on the parameter tree for less HBM traffic than a flatten,
+kernel and scatter.  The sqnorm kernels stay for the parity tier and
+``benchmarks/perf_comm.py``.  This package flattens the gradient pytree ONCE
 into a single padded ``(rows, 128)`` buffer with a static leaf-offset
 table (:mod:`repro.fastpath.layout`), then issues ONE batched launch per
 round per quantity with grid (workers × row-blocks)

@@ -1,7 +1,14 @@
 """``FastPathPlan`` — the batched comm plane, resolved once per policy.
 
 A plan is what ``sqnorm_fn`` / ``use_pallas`` used to be: the policy's
-route to accelerated trigger/encode math.  It owns
+route to accelerated trigger/encode math.  On TPU it serves LAQ, whose
+encode needs the flat buffer; the dense policies (gd, lag-wk, lag-ps,
+lasg-wk) return None from ``fast_precompute`` and run their reduce and
+masked fold as XLA fusions on the parameter tree, where the plane's
+flatten and scatter would cost more than its kernels save.
+:meth:`FastPathPlan.sqnorm` and :meth:`FastPathPlan.delta_sqnorm` serve
+no round; the parity tier and ``benchmarks/perf_comm.py`` call
+them.  It owns
 
   * the activation decision (``mode="auto"`` → on when running on TPU,
     except below the dispatch floor and for a round a multi-device mesh

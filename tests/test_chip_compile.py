@@ -1,10 +1,12 @@
-"""The comm plane's kernels compiled for a described TPU v5e at real width.
+"""The comm plane's kernels compiled for a described TPU v5e at real width,
+and the route a one-chip train step takes through them.
 
 The TPU compiler runs here without a chip: ``get_topology_desc`` describes
 a v5e 2x2, and ``jit(...).lower(shapes).compile()`` runs Mosaic and XLA:TPU
 as the chip would. It refuses misaligned blocks, VMEM overflow and HBM
 overflow, which interpret mode cannot see. Nothing runs, so these tests
-check only that each kernel compiles and is a real Mosaic kernel.
+check only that each kernel compiles and is a real Mosaic kernel, and
+which policy's step holds one: lag-wk's none, laq@4's the plane's.
 
 The shapes are mamba2-370m's flat comm-plane buffer: 368,338,432 f32
 parameters, which make 2,877,696 rows × 128 lanes per worker.
@@ -106,3 +108,43 @@ def test_kernel_compiles_for_v5e(one_chip, mamba_rows, no_persistent_cache,
     fn, args = _case(name, W, R, sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("algo,plane", [("lag-wk", False), ("laq@4", True)])
+def test_train_step_route_for_v5e(topo, no_persistent_cache, monkeypatch,
+                                  algo, plane):
+    """The one-chip ``shards`` train step of a small dense model with the
+    auto plan active, as on the chip: lag-wk runs its trigger and ĝ fold
+    as XLA fusions on the parameter tree (no Mosaic kernel, no op under
+    ``lag.layout``); laq@4 still takes the plane and its flat layout."""
+    import repro.kernels
+    from repro import fastpath
+    from repro.dist import (TrainerConfig, batch_shardings, lag_trainer,
+                            make_train_step, tree_shardings)
+    from repro.launch.mesh import make_mesh
+
+    monkeypatch.setattr(repro.kernels, "on_tpu", lambda: True)
+    W = 2
+    cfg = get_config("granite-8b").reduced()
+    tcfg = TrainerConfig(algo=algo, num_workers=W, fastpath="auto")
+    state = jax.eval_shape(lambda k: lag_trainer.state_for_params(
+        model.init(k, cfg), tcfg), jax.random.PRNGKey(0))
+    plan = fastpath.active_plan(tcfg.comm_policy())
+    assert plan is not None and not plan.interpret
+    assert not plan.below_dispatch_floor(state["lag"]["grad_hat"])
+
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    tokens = jax.ShapeDtypeStruct((4, 64), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens}
+
+    def spec(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    with jax.set_mesh(mesh):
+        text = jax.jit(make_train_step(cfg, tcfg), donate_argnums=(0,)).lower(
+            spec(state, tree_shardings(state, mesh)),
+            spec(batch, batch_shardings(batch, mesh))).compile().as_text()
+    assert ("tpu_custom_call" in text) == plane
+    assert ("/lag.layout/" in text) == plane

@@ -1,8 +1,9 @@
 """The kernel-parity tier for the batched flat-buffer comm plane
 (``repro.fastpath``): the batched plane vs the jnp oracle across dtypes,
 ragged/empty leaf sizes, worker counts and LAQ bit widths; layout
-round-trips; seed-repeat reduction determinism; the lag-wk 50-step golden
-with the plane forced on; and the forced-mode error paths.
+round-trips; seed-repeat reduction determinism; which policies an active
+plan serves (LAQ only); the lag-wk 50-step golden with the plane forced
+on; and the forced-mode error paths.
 
 Mirrors tests/test_comm.py's twin structure: the hypothesis property
 tests at the bottom deepen coverage where the optional dep is installed
@@ -358,7 +359,7 @@ def test_small_shape_dispatch_choice(monkeypatch):
     """The convex-d50 M=1 regression fix: under an ACTIVE auto plan,
     ``policy_rounds`` must route sub-floor stacked trees straight to the
     jnp oracle — ``fast_precompute`` is never consulted — while at-floor
-    trees still ride the plane."""
+    trees ask the policy for its plane route."""
     from repro import comm
     from repro.engine import rounds
     from repro.fastpath import plan as plan_mod
@@ -386,6 +387,71 @@ def test_small_shape_dispatch_choice(monkeypatch):
     assert calls == []
     run(4)                   # 256 × 4 = 1024: the plane serves it
     assert calls == [4]
+
+
+#: the plan's pytree-level ops a round can reach
+PLANE_OPS = ("sqnorm", "delta_sqnorm", "masked_add", "masked_select",
+             "laq_encode_flat")
+
+
+@pytest.mark.parametrize("spec", ["lag-wk", "lag-ps", "lasg-wk", "laq@4"])
+def test_active_auto_plan_serves_laq_only(monkeypatch, spec):
+    """Under an ACTIVE auto plan at a size above the dispatch floor, the
+    dense policies run their round as the jnp oracle (per-leaf XLA
+    fusions: no plane op, so no flatten, launch or scatter) and give
+    exactly what ``fastpath="off"`` gives; LAQ still takes the plane's
+    encode and makes the same upload decisions."""
+    from repro import comm
+    from repro.engine import rounds
+    from repro.fastpath import plan as plan_mod
+
+    W = 4
+    monkeypatch.setattr(plan_mod, "on_tpu", lambda: True)   # activate auto
+    # ...while LAQ's kernels still run through the Pallas interpreter here
+    monkeypatch.setattr(FastPathPlan, "interpret",
+                        property(lambda self: True))
+    calls = []
+    for op in PLANE_OPS:
+        def spy(self, *a, _op=op, _orig=getattr(FastPathPlan, op), **kw):
+            calls.append(_op)
+            return _orig(self, *a, **kw)
+        monkeypatch.setattr(FastPathPlan, op, spy)
+
+    sizes = (50, 7)
+    params = make_tree(sizes, seed=1)
+    grads = make_tree(sizes, W=W, seed=2)
+    # per-worker innovations from none to large: against RHS = 1 the
+    # first two workers skip and the last two upload
+    scale = jnp.asarray([0.0, 0.03, 0.3, 3.0])
+    step = jax.tree_util.tree_map(lambda n: scale[:, None] * n,
+                                  make_tree(sizes, W=W, seed=3))
+    theta_hat = jax.tree_util.tree_map(lambda p, s: p + s, params, step)
+    ghat = lag.tree_sub(grads, step)
+    cfg = lag.LAGConfig(num_workers=W, alpha=0.1, D=2, xi=0.1)
+
+    def run(fp):
+        policy = comm.make_policy(spec, fastpath=fp)
+        st = policy.init_state(ghat, theta0=theta_hat)
+        st.update(hist=jnp.full((2,), 0.8, jnp.float32),
+                  L_m=jnp.ones((W,), jnp.float32))
+        gah = ghat if policy.needs_grad_at_hat else None
+        return policy, rounds.policy_rounds(policy, cfg, params, grads, st,
+                                            gah)
+
+    _, (comm_off, delta_off, st_off) = run("off")
+    assert calls == []
+    policy, (comm_on, delta_on, st_on) = run("auto")
+    assert fastpath.active_plan(policy) is not None
+    assert not policy.fastpath.below_dispatch_floor(grads)
+    np.testing.assert_array_equal(np.asarray(comm_on), [0, 0, 1, 1])
+    np.testing.assert_array_equal(np.asarray(comm_on), np.asarray(comm_off))
+    if spec.startswith("laq"):
+        assert "laq_encode_flat" in calls
+        return
+    assert calls == []
+    for a, b in zip(jax.tree_util.tree_leaves((delta_on, st_on)),
+                    jax.tree_util.tree_leaves((delta_off, st_off))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_small_shape_parity_convex_d50():
@@ -469,12 +535,12 @@ def test_new_policy_without_fast_route_trips(plan):
 # ---------------------------------------------------------------------------
 
 def test_lag_wk_golden_upload_decisions_with_fastpath_on():
-    """tests/golden/lag_wk_50step.json through the batched plane:
+    """tests/golden/lag_wk_50step.json with the plane forced on:
     per-round and per-worker upload decisions BIT-identical to the
-    recorded oracle trajectory (acceptance criterion).  Losses are
-    allclose at rtol=1e-4 — NOT bit-equal: the plane's f32 trigger LHS
-    sums block partials in layout order while the oracle sums leaf-major,
-    so the last-ulp of the LHS (and nothing else) may differ."""
+    recorded oracle trajectory (acceptance criterion).  lag-wk opts out
+    of the plane (its trigger and ĝ fold are per-leaf XLA fusions), so a
+    forced plan must leave its trajectory the oracle's.  Losses are
+    allclose at rtol=1e-4."""
     from repro.engine import Experiment
     gold = json.load(open(GOLDEN))
     r = Experiment(model="llama3.2-1b", algo="lag-wk", steps=50,
@@ -487,8 +553,9 @@ def test_lag_wk_golden_upload_decisions_with_fastpath_on():
 
 
 def test_convex_fastpath_decision_parity():
-    """One convex sweep, plane vs oracle: identical upload masks for a
-    trigger policy AND a quantized one (the two kernel-served families)."""
+    """One convex sweep, forced plan vs oracle: identical upload masks for
+    a trigger policy (which opts out of the plane) AND a quantized one
+    (which the plane serves)."""
     from repro.core import convex, simulate
     prob = convex.synthetic("linreg", num_workers=5, n_per=12, d=9, seed=2)
     for algo in ("lag-wk", "laq@3"):
